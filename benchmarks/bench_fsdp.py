@@ -50,7 +50,7 @@ _SCRIPT = """
                                     mesh, placement))
         bytes_dev = F.per_device_bytes((state.params, state.opt_state))
         fn = jax.jit(step_fn)
-        with mesh, activation_sharding(PT.dp_axes(mesh), PT.dp_size(mesh),
+        with jax.set_mesh(mesh), activation_sharding(PT.dp_axes(mesh), PT.dp_size(mesh),
                                        "model", 2):
             state, m = fn(state, batch, 0)           # compile + warm
             jax.block_until_ready(m["loss"])
@@ -76,6 +76,7 @@ _SCRIPT = """
 
 def run() -> None:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"        # virtual CPU devices; never the chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(_SCRIPT)],

@@ -111,7 +111,7 @@ _SCRIPT = """
                                attn_chunk=32, transport=tr)
         hints, hsize = tr.hint_axes(mesh)
         fn = jax.jit(step)
-        with mesh, activation_sharding(hints, hsize, "model", 2):
+        with jax.set_mesh(mesh), activation_sharding(hints, hsize, "model", 2):
             lowered = fn.lower(state, batch, 0)
             wb = wire_bytes(lowered.as_text())
             cost = analyze_hlo(lowered.compile().as_text())
@@ -159,6 +159,7 @@ _SCRIPT = """
 
 def _run_sub(smoke: bool) -> list[str]:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"        # virtual CPU devices; never the chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
     script = textwrap.dedent(_SCRIPT).format(smoke=smoke)

@@ -205,7 +205,7 @@ _HLO_SCRIPT = """
         step = make_train_step(cfg, policy, opt, constant(1e-3),
                                attn_chunk=32, transport=tr)
         hints, hsize = tr.hint_axes(mesh)
-        with mesh, activation_sharding(hints, hsize, "model", 2):
+        with jax.set_mesh(mesh), activation_sharding(hints, hsize, "model", 2):
             lowered = jax.jit(step).lower(state, batch, 0)
             pre = stablehlo_bytes(lowered.as_text())
             cost = analyze_hlo(lowered.compile().as_text())
@@ -222,6 +222,7 @@ _HLO_SCRIPT = """
 
 def _hlo_rows(wires: list[str]) -> list[str]:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"        # virtual CPU devices; never the chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
     script = textwrap.dedent(_HLO_SCRIPT).format(wires=wires)

@@ -55,6 +55,7 @@ def main() -> None:
     args = ap.parse_args()
     only = args.only.split(",") if args.only else None
     print("name,us_per_call,derived")
+    failed = []
     for name, module in SECTIONS:
         if only and not any(name.startswith(o) for o in only):
             continue
@@ -70,7 +71,8 @@ def main() -> None:
             kwargs["smoke"] = True
         try:
             mod.run(**kwargs)
-        except Exception as e:  # keep the suite going; report the failure
+        except Exception as e:  # finish the other sections, then exit 1
+            failed.append(name)
             if sess is not None:
                 sess.error = f"{type(e).__name__}: {e}"
             print(f"{name}_ERROR,0.0,{type(e).__name__}:{e}", file=sys.stderr)
@@ -82,6 +84,8 @@ def main() -> None:
                 sess.write(path)
                 print(f"# profile -> {path}", file=sys.stderr)
         print(f"# section {name} took {time.time() - t0:.1f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

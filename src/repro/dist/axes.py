@@ -7,7 +7,7 @@ re-entry, flash-attention scan carries). The helpers read a thread-local
 context manager — the train launcher and the dry-run compiler enter it
 together with the mesh:
 
-    with mesh, activation_sharding(("data",), 4, "model", 2):
+    with jax.set_mesh(mesh), activation_sharding(("data",), 4, "model", 2):
         jax.jit(step_fn)(state, batch, 0)
 
 Outside the context (or outside any active mesh) every helper returns its
@@ -29,11 +29,10 @@ import threading
 from typing import Optional
 
 import jax
-from jax.interpreters import pxla
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["ActivationSharding", "activation_sharding", "current_sharding",
-           "shard_batch", "shard_heads", "padded_head_count"]
+           "in_mesh", "shard_batch", "shard_heads", "padded_head_count"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,8 +63,9 @@ def activation_sharding(dp_axes, dp_size: int, model_axis: str, mp_size: int):
         _local.ctx = prev
 
 
-def _in_mesh() -> bool:
-    return not pxla.thread_resources.env.physical_mesh.empty
+def in_mesh() -> bool:
+    """True inside ``jax.set_mesh(mesh)``."""
+    return not jax.sharding.get_abstract_mesh().empty
 
 
 def _constrain(x, pinned: dict[int, object]):
@@ -78,7 +78,7 @@ def _constrain(x, pinned: dict[int, object]):
 def shard_batch(x, axis: int = 0):
     """Pin dimension ``axis`` (the batch) to the data-parallel axes."""
     ctx = current_sharding()
-    if (ctx is None or ctx.dp_size <= 1 or not _in_mesh()
+    if (ctx is None or ctx.dp_size <= 1 or not in_mesh()
             or x.ndim <= axis or x.shape[axis] % ctx.dp_size):
         return x
     return _constrain(x, {axis: ctx.dp_axes})
@@ -92,7 +92,7 @@ def shard_heads(x, axis: int):
     a replicated fixed point. No-op when the head count does not divide.
     """
     ctx = current_sharding()
-    if ctx is None or not _in_mesh():
+    if ctx is None or not in_mesh():
         return x
     pinned: dict[int, object] = {}
     if ctx.mp_size > 1 and x.shape[axis] % ctx.mp_size == 0:

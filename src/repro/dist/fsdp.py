@@ -34,10 +34,10 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.interpreters import pxla
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.dist import partition as PT
+from repro.dist.axes import in_mesh
 from repro.dist.partition import Placement
 
 __all__ = ["unshard_spec", "gather_specs", "all_gather_params",
@@ -47,10 +47,6 @@ __all__ = ["unshard_spec", "gather_specs", "all_gather_params",
 PyTree = Any
 
 _is_spec = lambda x: isinstance(x, P)  # noqa: E731 — tree_map leaf predicate
-
-
-def _in_mesh() -> bool:
-    return not pxla.thread_resources.env.physical_mesh.empty
 
 
 def unshard_spec(spec: P, placement: Placement) -> P:
@@ -76,7 +72,7 @@ def gather_specs(pspecs: PyTree, placement: Placement) -> PyTree:
 
 def constrain(tree: PyTree, specs: PyTree) -> PyTree:
     """``with_sharding_constraint`` leaf-for-leaf; no-op outside a mesh."""
-    if not _in_mesh():
+    if not in_mesh():
         return tree
     return jax.tree_util.tree_map(
         lambda x, s: jax.lax.with_sharding_constraint(x, s), tree, specs)
@@ -90,7 +86,7 @@ def all_gather_params(params: PyTree, pspecs: PyTree,
     all-gather then moves bf16 on the wire, half the bytes of gathering
     storage-format masters.
     """
-    if placement.fsdp_axis is None or not _in_mesh():
+    if placement.fsdp_axis is None or not in_mesh():
         return params
     return constrain(params, gather_specs(pspecs, placement))
 
@@ -108,7 +104,7 @@ def reduce_scatter_grads(grads: PyTree, pspecs: PyTree,
     backend keeps all-reduce + slice, which is numerically identical but
     transiently materializes the unsharded gradient).
     """
-    if placement.fsdp_axis is None or not _in_mesh():
+    if placement.fsdp_axis is None or not in_mesh():
         return grads
     return constrain(grads, pspecs)
 

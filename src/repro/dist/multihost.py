@@ -67,12 +67,9 @@ def initialize(coordinator: str | None = None,
             "multihost.initialize: coordinator and num_processes are set "
             "but process_id is not — pass process_id= (--process-id) or "
             f"set {ENV_PROCESS_ID}")
-    try:
-        # the CPU client ships cross-process collectives only via gloo;
-        # harmless when another backend ends up selected
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass  # unknown on this jax version — platform default applies
+    # the CPU client ships cross-process collectives only via gloo;
+    # harmless when another backend ends up selected
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id,

@@ -59,14 +59,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._compat import ensure_shard_map
 from repro.core.formats import BF16, FORMATS, FP32, FloatFormat
 from repro.dist import fsdp as F
 from repro.dist import partition as PT
 from repro.dist.partition import Placement
 from repro.optim import grad_compress as GC
-
-ensure_shard_map()
 
 __all__ = ["GradientTransport", "Fp32Psum", "ReduceScatter",
            "CompressedWire", "WirePolicy", "make_transport"]

@@ -32,6 +32,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_decode_attention", "decode_attention_kernel",
            "fused_paged_decode_attention", "paged_decode_attention_kernel"]
@@ -39,28 +40,42 @@ __all__ = ["fused_decode_attention", "decode_attention_kernel",
 NEG_INF = -1e30
 
 
-def decode_attention_kernel(q_ref, k_ref, v_ref, kpos_ref, qpos_ref,
+def _attend(q_ref, k, v, k_pos, q_pos, out_ref, *, scale, window, softcap,
+            p_dtype):
+    """Score/mask/softmax/PV for one lane, one KV head at a time.
+
+    q_ref (1,Hkv,G,D); k/v (Sc, Hkv·D) — the KV heads side by side on the
+    lane axis, so a (Sc, 2, 128) cache row costs no sublane padding in
+    VMEM; k_pos (1,Sc). Per head the contractions are plain 2-D matmuls.
+    """
+    hkv, d = q_ref.shape[1], q_ref.shape[3]
+    ok = (k_pos <= q_pos) & (k_pos >= 0)                # (1, Sc)
+    if window is not None:
+        ok &= q_pos - k_pos < window
+    for h in range(hkv):
+        q = q_ref[0, h]                                 # (G, D)
+        kh = k[:, h * d:(h + 1) * d]                    # (Sc, D)
+        s = jax.lax.dot_general(q, kh, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if softcap:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(ok, s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        out_ref[0, h] = jnp.dot(p.astype(p_dtype), v[:, h * d:(h + 1) * d],
+                                preferred_element_type=jnp.float32)
+
+
+def decode_attention_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
                             out_ref, *, scale: float, window, softcap,
                             p_dtype):
-    """One lane: q (1,Hkv,G,D); k/v (1,Sc,Hkv,D); kpos (1,Sc); qpos (1,1)."""
-    q_pos = qpos_ref[0, 0]
+    """One lane: qpos (B,) in SMEM; q (1,Hkv,G,D); k/v (1,Sc,Hkv·D);
+    kpos (1,1,Sc)."""
+    q_pos = qpos_ref[pl.program_id(0)]
 
     @pl.when(q_pos >= 0)
     def _active():
-        q = q_ref[0]                                   # (Hkv, G, D)
-        k = k_ref[0]                                   # (Sc, Hkv, D)
-        k_pos = kpos_ref[0]                            # (Sc,)
-        s = jnp.einsum("hgd,khd->hgk", q, k,
-                       preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        ok = (k_pos[None, None, :] <= q_pos) & (k_pos[None, None, :] >= 0)
-        if window is not None:
-            ok &= q_pos - k_pos[None, None, :] < window
-        s = jnp.where(ok, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out_ref[0] = jnp.einsum("hgk,khd->hgd", p.astype(p_dtype), v_ref[0],
-                                preferred_element_type=jnp.float32)
+        _attend(q_ref, k_ref[0], v_ref[0], kpos_ref[0], q_pos, out_ref,
+                scale=scale, window=window, softcap=softcap, p_dtype=p_dtype)
 
     @pl.when(q_pos < 0)
     def _parked():
@@ -80,65 +95,54 @@ def fused_decode_attention(q, k_cache, v_cache, k_pos, q_pos, *,
     _, Sc, Hkv, _ = k_cache.shape
     group = Hq // Hkv
     qg = q.reshape(B, Hkv, group, D)
-    qp = q_pos.reshape(B, 1).astype(jnp.int32)
     scale = 1.0 / (D ** 0.5)
 
-    q_bs = pl.BlockSpec((1, Hkv, group, D), lambda i: (i, 0, 0, 0))
-    kv_bs = pl.BlockSpec((1, Sc, Hkv, D), lambda i: (i, 0, 0, 0))
+    q_bs = pl.BlockSpec((1, Hkv, group, D), lambda i, qp: (i, 0, 0, 0))
+    kv_bs = pl.BlockSpec((1, Sc, Hkv * D), lambda i, qp: (i, 0, 0))
     out = pl.pallas_call(
         partial(decode_attention_kernel, scale=scale, window=window,
                 softcap=softcap, p_dtype=p_dtype),
-        grid=(B,),
-        in_specs=[q_bs, kv_bs, kv_bs,
-                  pl.BlockSpec((1, Sc), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_specs=q_bs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[q_bs, kv_bs, kv_bs,
+                      pl.BlockSpec((1, 1, Sc), lambda i, qp: (i, 0, 0))],
+            out_specs=q_bs),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), jnp.float32),
         interpret=interpret,
-    )(qg, k_cache, v_cache, k_pos, qp)
+    )(q_pos.astype(jnp.int32), qg, k_cache.reshape(B, Sc, Hkv * D),
+      v_cache.reshape(B, Sc, Hkv * D), k_pos.reshape(B, 1, Sc))
     return out.reshape(B, 1, Hq, D)
 
 
-def paged_decode_attention_kernel(q_ref, kp_ref, vp_ref, pp_ref, table_ref,
-                                  qpos_ref, out_ref, *, scale: float,
+def paged_decode_attention_kernel(qpos_ref, table_ref, q_ref, kp_ref, vp_ref,
+                                  pp_ref, out_ref, *, scale: float,
                                   n_blocks: int, window, softcap, p_dtype):
     """One lane against the paged pool.
 
-    q (1,Hkv,G,D); kp/vp (R,P,Hkv,D) and pp (R,P) are the *full* pool
-    (block index maps pin them, so every lane reads the same blocks);
-    table (1,n_blocks) maps the lane's logical blocks to pool rows;
-    qpos (1,1). The lane's KV view is gathered row-by-row with dynamic
-    loads — ``n_blocks`` is static, so the gather unrolls — and then
-    runs the exact score/mask/softmax/PV pipeline of
-    :func:`decode_attention_kernel`: token at logical position p sits at
-    view index p, so the result is bitwise-identical to the contiguous
-    kernel on an equal-length cache.
+    qpos (B,) and table (B·n_blocks,) are scalar-prefetched into SMEM;
+    q (1,Hkv,G,D); kp/vp (R,P,Hkv·D) and pp (R,1,P) are the *full* pool
+    (block index maps pin them, so every lane reads the same blocks).
+    The lane's KV view is gathered row-by-row with dynamic loads —
+    ``n_blocks`` is static, so the gather unrolls — and then runs the
+    exact score/mask/softmax/PV pipeline of :func:`decode_attention_kernel`:
+    token at logical position p sits at view index p, so the result is
+    bitwise-identical to the contiguous kernel on an equal-length cache.
     """
-    q_pos = qpos_ref[0, 0]
+    i = pl.program_id(0)
+    q_pos = qpos_ref[i]
 
     @pl.when(q_pos >= 0)
     def _active():
-        q = q_ref[0]                                   # (Hkv, G, D)
         ks, vs, ps = [], [], []
         for b in range(n_blocks):
-            pg = table_ref[0, b]
-            ks.append(pl.load(kp_ref, (pl.ds(pg, 1),) + (slice(None),) * 3))
-            vs.append(pl.load(vp_ref, (pl.ds(pg, 1),) + (slice(None),) * 3))
-            ps.append(pl.load(pp_ref, (pl.ds(pg, 1), slice(None))))
-        k = jnp.concatenate(ks, axis=1)[0]             # (n_blocks·P, Hkv, D)
-        v = jnp.concatenate(vs, axis=1)[0]
-        k_pos = jnp.concatenate(ps, axis=1)[0]         # (n_blocks·P,)
-        s = jnp.einsum("hgd,khd->hgk", q, k,
-                       preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        ok = (k_pos[None, None, :] <= q_pos) & (k_pos[None, None, :] >= 0)
-        if window is not None:
-            ok &= q_pos - k_pos[None, None, :] < window
-        s = jnp.where(ok, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out_ref[0] = jnp.einsum("hgk,khd->hgd", p.astype(p_dtype), v,
-                                preferred_element_type=jnp.float32)
+            pg = table_ref[i * n_blocks + b]
+            ks.append(kp_ref[pg])                      # (P, Hkv·D)
+            vs.append(vp_ref[pg])
+            ps.append(pp_ref[pg])                      # (1, P)
+        _attend(q_ref, jnp.concatenate(ks, axis=0), jnp.concatenate(vs, axis=0),
+                jnp.concatenate(ps, axis=1), q_pos, out_ref, scale=scale,
+                window=window, softcap=softcap, p_dtype=p_dtype)
 
     @pl.when(q_pos < 0)
     def _parked():
@@ -154,12 +158,11 @@ def fused_paged_decode_attention(q, k_pages, v_pages, pos_pages, block_table,
     q_pos: (B,) i32 (−1 ⇒ parked lane). Returns f32 (B,1,Hq,D) —
     unrounded, the caller applies the policy's single output rounding.
 
-    The pool rides into the kernel as one whole-array block per operand
-    (the lane's pages are gathered in-kernel via the table). That is the
-    right CI-grade shape — interpret mode and single-device TPU smoke
-    share it — while a TPU-native variant would stream pages by scalar
-    prefetch (``PrefetchScalarGridSpec``); see docs/serving.md and the
-    ROADMAP TPU item.
+    The pool rides into the kernel as one whole-array VMEM block per
+    operand (the lane's pages are gathered in-kernel via the table), so
+    the pool must fit the kernel's VMEM: the chip's compiler refuses a
+    larger one (docs/kernels.md gives the largest that compiled). A
+    kernel that streams pages by scalar prefetch is the real-size form.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -168,23 +171,23 @@ def fused_paged_decode_attention(q, k_pages, v_pages, pos_pages, block_table,
     n_blocks = block_table.shape[1]
     group = Hq // Hkv
     qg = q.reshape(B, Hkv, group, D)
-    qp = q_pos.reshape(B, 1).astype(jnp.int32)
     scale = 1.0 / (D ** 0.5)
 
-    q_bs = pl.BlockSpec((1, Hkv, group, D), lambda i: (i, 0, 0, 0))
+    q_bs = pl.BlockSpec((1, Hkv, group, D), lambda i, qp, t: (i, 0, 0, 0))
+    pool_bs = pl.BlockSpec((R, P, Hkv * D), lambda i, qp, t: (0, 0, 0))
     out = pl.pallas_call(
         partial(paged_decode_attention_kernel, scale=scale,
                 n_blocks=n_blocks, window=window, softcap=softcap,
                 p_dtype=p_dtype),
-        grid=(B,),
-        in_specs=[q_bs,
-                  pl.BlockSpec((R, P, Hkv, D), lambda i: (0, 0, 0, 0)),
-                  pl.BlockSpec((R, P, Hkv, D), lambda i: (0, 0, 0, 0)),
-                  pl.BlockSpec((R, P), lambda i: (0, 0)),
-                  pl.BlockSpec((1, n_blocks), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_specs=q_bs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[q_bs, pool_bs, pool_bs,
+                      pl.BlockSpec((R, 1, P), lambda i, qp, t: (0, 0, 0))],
+            out_specs=q_bs),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), jnp.float32),
         interpret=interpret,
-    )(qg, k_pages, v_pages, pos_pages, block_table, qp)
+    )(q_pos.astype(jnp.int32), block_table.reshape(-1).astype(jnp.int32), qg,
+      k_pages.reshape(R, P, Hkv * D), v_pages.reshape(R, P, Hkv * D),
+      pos_pages.reshape(R, 1, P))
     return out.reshape(B, 1, Hq, D)

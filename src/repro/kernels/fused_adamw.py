@@ -9,8 +9,8 @@ HLO ops of Alg. 4); the fusion removes that traffic (see
 benchmarks/bench_kernels.py).
 
 Variants (compile-time flags): update_rounding ∈ {nearest, stochastic},
-kahan ∈ {off, on}. All tensors bf16 except c1/c2/lr scalars (f32 SMEM-
-style inputs, passed as (1,1) blocks).
+kahan ∈ {off, on}. All tensors bf16 except the f32 scalars (lr, betas,
+eps, wd, bias corrections), which ride in SMEM as one (1, 7) array.
 """
 from __future__ import annotations
 
@@ -19,6 +19,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_adamw", "fused_adamw_kernel"]
 
@@ -116,7 +117,7 @@ def fused_adamw(w, m, v, g, *, c=None, bits=None, lr, b1, b2, eps, wd,
         partial(fused_adamw_kernel, stochastic=stochastic, kahan=kahan),
         grid=grid,
         in_specs=[bs, bs, bs, bs, bs, bs,
-                  pl.BlockSpec((1, 7), lambda i: (0, 0))],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[bs, bs, bs, bs],
         out_shape=[out_sds, out_sds, out_sds, out_sds],
         interpret=interpret,

@@ -9,6 +9,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_adamw import _pad2, _sr_to_bf16, BLOCK_ROWS, LANE
 
@@ -67,7 +68,7 @@ def fused_sgd(w, m, g, *, c=None, bits=None, lr, momentum=0.9, wd=0.0,
     w2, m2, c2 = pl.pallas_call(
         partial(fused_sgd_kernel, stochastic=stochastic, kahan=kahan),
         grid=(grid_rows // block_rows,),
-        in_specs=[bs, bs, bs, bs, bs, pl.BlockSpec((1, 3), lambda i: (0, 0))],
+        in_specs=[bs, bs, bs, bs, bs, pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=[bs, bs, bs],
         out_shape=[out_sds, out_sds, out_sds],
         interpret=interpret,

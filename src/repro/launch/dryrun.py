@@ -123,7 +123,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, policy_name: str = "bf16_sr"
         else:
             step_fn = make_train_step(cfg, policy, opt, constant(1e-4),
                                       grad_accum=grad_accum)
-        with mesh, activation_sharding(hint_dp, hint_dp_size, "model",
+        with jax.set_mesh(mesh), activation_sharding(hint_dp, hint_dp_size, "model",
                                        mesh.shape["model"]):
             lowered = jax.jit(step_fn, donate_argnums=(0,)).lower(
                 state_in, batch_in, jax.ShapeDtypeStruct((), jnp.int32))
@@ -136,7 +136,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, policy_name: str = "bf16_sr"
             logits = R.forward_logits(qa, params, cfg, batch, remat=False)
             return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
 
-        with mesh, activation_sharding(dp, dp_size, "model", mesh.shape["model"]):
+        with jax.set_mesh(mesh), activation_sharding(dp, dp_size, "model", mesh.shape["model"]):
             lowered = jax.jit(prefill_step).lower(params_in, batch_in)
     else:  # decode
         B, S = shape.global_batch, shape.seq_len
@@ -170,7 +170,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, policy_name: str = "bf16_sr"
             args.append(jax.ShapeDtypeStruct(
                 (3, B, 1), jnp.int32,
                 sharding=NamedSharding(mesh, P(None, tok_spec[0], None))))
-        with mesh, activation_sharding(dp, dp_size, "model", mesh.shape["model"]):
+        with jax.set_mesh(mesh), activation_sharding(dp, dp_size, "model", mesh.shape["model"]):
             lowered = jax.jit(serve, donate_argnums=(1,)).lower(*args)
 
     compiled = lowered.compile()

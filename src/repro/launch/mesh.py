@@ -1,19 +1,26 @@
-"""Production mesh builders (per spec: function, no module-level jax state).
+"""Mesh construction: the one place a ``jax.sharding.Mesh`` is built.
 
 Axis names are validated against the :mod:`repro.dist.partition`
 constants so a typo'd mesh can never silently replicate what the
-placement meant to shard.
+placement meant to shard. Every axis is ``AxisType.Auto``: the
+placement rules and the ``with_sharding_constraint`` hints leave the
+unpinned dimensions to GSPMD propagation, which ``Explicit`` axes (the
+``jax.make_mesh`` default) refuse.
 """
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.sharding import AxisType
 
 from repro.dist import partition as PT
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh"]
 
 
-def _validated_mesh(shape, axes, devices=None):
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with validated axis names and ``Auto`` axis types."""
     unknown = [a for a in axes if a not in PT.KNOWN_AXES]
     if unknown:
         raise ValueError(
@@ -21,7 +28,6 @@ def _validated_mesh(shape, axes, devices=None):
             f"understand {list(PT.KNOWN_AXES)}")
     if len(set(axes)) != len(axes):
         raise ValueError(f"duplicate mesh axis names: {axes}")
-    import math
     want = math.prod(shape)
     have = len(devices) if devices is not None else jax.device_count()
     if want > have:
@@ -34,7 +40,8 @@ def _validated_mesh(shape, axes, devices=None):
             f"× {jax.local_device_count()} local) — under jax.distributed "
             f"the mesh spans every host's devices; size the axes to the "
             f"global count")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, fsdp: int = 1):
@@ -57,7 +64,7 @@ def make_production_mesh(*, multi_pod: bool = False, fsdp: int = 1):
     if multi_pod:
         shape = (2,) + shape
         axes = (PT.POD_AXIS,) + axes
-    return _validated_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1, fsdp: int = 1,
@@ -85,4 +92,4 @@ def make_local_mesh(data: int = 1, model: int = 1, fsdp: int = 1,
     if pods > 1:
         shape = (pods,) + shape
         axes = (PT.POD_AXIS,) + axes
-    return _validated_mesh(shape, axes, devices)
+    return make_mesh(shape, axes, devices)

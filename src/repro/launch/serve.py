@@ -38,6 +38,8 @@ import jax
 import numpy as np
 
 from repro.core.policy import get_policy
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh
 from repro.models import registry as R
 from repro.serve.engine import Engine
 
@@ -114,6 +116,7 @@ def main():
                          "full-context stacks)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     policy = get_policy(args.policy)
     cfg = R.get_config(args.arch)
     if args.reduced:
@@ -122,8 +125,7 @@ def main():
 
     mesh = None
     if args.data_parallel:
-        mesh = jax.make_mesh((args.data_parallel, args.model_parallel),
-                             ("data", "model"))
+        mesh = make_local_mesh(args.data_parallel, args.model_parallel)
     engine = Engine(params, cfg, policy, n_slots=args.slots,
                     max_len=args.max_len, mesh=mesh, eos_id=args.eos_id,
                     fused_decode=args.fused_decode, paged=args.paged,

@@ -35,9 +35,10 @@ state onto the *current* mesh instead of restoring it unsharded.
 from __future__ import annotations
 
 import argparse
+import contextlib
+from typing import Any, Callable, NamedTuple
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.policy import get_policy
 from repro.data.synthetic import lm_batches
@@ -46,12 +47,13 @@ from repro.dist import multihost as MH
 from repro.dist import partition as PT
 from repro.dist import transport as TR
 from repro.dist.axes import activation_sharding
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import registry as R
 from repro.optim import adamw, fused_adamw_optimizer, linear_warmup_cosine
 from repro.train.loop import TrainLoopConfig, run_training
 from repro.train.step import make_train_step
-from repro.train.train_state import make_train_state
+from repro.train.train_state import TrainState, make_train_state
 
 
 def main():
@@ -121,6 +123,7 @@ def main():
                          "agreement every this many steps")
     args = ap.parse_args()
 
+    enable_compile_cache()
     # must precede any backend/device use in the process
     MH.initialize(coordinator=args.coordinator,
                   num_processes=args.num_processes,
@@ -130,18 +133,6 @@ def main():
     cfg = R.get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params = R.init(cfg, jax.random.PRNGKey(args.seed), policy.param_dtype)
-    lr_schedule = linear_warmup_cosine(
-        args.lr, max(args.steps // 20, 1), args.steps)
-
-    def make_opt(mesh=None, pspecs=None):
-        # the fused kernels run shard-local (inside shard_map) on a mesh,
-        # so the optimizer is built only after the placement is known
-        if args.fused_update:
-            return fused_adamw_optimizer(policy, b2=0.997, weight_decay=0.01,
-                                         mesh=mesh, pspecs=pspecs)
-        return adamw(policy, b2=0.997, weight_decay=0.01)
-
     wire_policy = (TR.WirePolicy.parse(args.wire_keep_fp32)
                    if args.wire_keep_fp32 is not None else None)
     dp, mp, fp, pods = (args.data_parallel, args.model_parallel,
@@ -151,41 +142,88 @@ def main():
         # every global device (a single-device mesh would leave the
         # other hosts' devices idle and the collectives unformed)
         dp = jax.device_count()
-    use_fsdp = args.fsdp or fp > 1
-    if dp * mp * fp * pods > 1:
-        mesh = make_local_mesh(dp, mp, fsdp=fp, pods=pods)
-        placement = PT.default_placement(mesh, fsdp=use_fsdp)
-        pspecs = PT.param_specs(params, cfg, mesh, placement)
-        opt = make_opt(mesh, pspecs)
-        transport = TR.make_transport(mesh=mesh, placement=placement,
-                                      pspecs=pspecs, wire=args.grad_wire,
-                                      wire_policy=wire_policy)
-        state = make_train_state(params, opt, transport=transport)
-        shardings = F.train_state_shardings(state, cfg, mesh, placement,
+    mesh = (make_local_mesh(dp, mp, fsdp=fp, pods=pods)
+            if dp * mp * fp * pods > 1 else None)
+    run = build_training(cfg, policy, seed=args.seed, lr=args.lr,
+                         steps=args.steps, seq=args.seq, mesh=mesh,
+                         fsdp=args.fsdp or fp > 1,
+                         fused_update=args.fused_update,
+                         grad_wire=args.grad_wire, wire_policy=wire_policy,
+                         grad_accum=args.grad_accum)
+    with run.scope():
+        _run(run, cfg, args)
+
+
+class Training(NamedTuple):
+    state: TrainState
+    step: Callable               # jitted; donates the state it is given
+    shardings: Any               # TrainState sharding tree, None off-mesh
+    transport: TR.GradientTransport
+    scope: Callable[[], contextlib.AbstractContextManager]
+
+
+def build_training(cfg, policy, *, seed: int, lr: float, steps: int,
+                   seq: int, mesh=None, fsdp: bool = False,
+                   fused_update: bool = False, grad_wire: str = "fp32",
+                   wire_policy=None, grad_accum: int = 1) -> Training:
+    """The launcher's training state and step, on ``mesh`` or one device.
+
+    The state is made by one jitted ``R.init`` + ``make_train_state``
+    straight into its shardings, so no device ever holds more than its
+    share of it, and the step is jitted donating the state: the update
+    writes over the old state's buffers instead of beside them. A step
+    that fails after it has consumed its state is not retried
+    (``run_training`` raises; the run restarts from its last
+    checkpoint). ``scope()`` is the context the step runs in: the mesh
+    and the activation-sharding hints.
+    """
+    key = jax.random.PRNGKey(seed)
+    params_shape = jax.eval_shape(
+        lambda k: R.init(cfg, k, policy.param_dtype), key)
+    placement = pspecs = shardings = None
+    if mesh is not None:
+        placement = PT.default_placement(mesh, fsdp=fsdp)
+        pspecs = PT.param_specs(params_shape, cfg, mesh, placement)
+    # the fused kernels run shard-local (inside shard_map) on a mesh, so
+    # the optimizer is built only after the placement is known
+    opt = (fused_adamw_optimizer(policy, b2=0.997, weight_decay=0.01,
+                                 mesh=mesh, pspecs=pspecs)
+           if fused_update else adamw(policy, b2=0.997, weight_decay=0.01))
+    transport = TR.make_transport(mesh=mesh, placement=placement,
+                                  pspecs=pspecs, wire=grad_wire,
+                                  wire_policy=wire_policy)
+
+    def init(k):
+        return make_train_state(R.init(cfg, k, policy.param_dtype), opt,
+                                transport=transport)
+
+    if mesh is not None:
+        shardings = F.train_state_shardings(jax.eval_shape(init, key), cfg,
+                                            mesh, placement,
                                             transport=transport)
-        state = jax.device_put(state, shardings)
-        step_fn = make_train_step(cfg, policy, opt, lr_schedule,
-                                  transport=transport,
-                                  grad_accum=args.grad_accum,
-                                  attn_chunk=min(1024, args.seq))
+    state = jax.jit(init, out_shardings=shardings)(key)
+    step_fn = make_train_step(cfg, policy, opt,
+                              linear_warmup_cosine(lr, max(steps // 20, 1),
+                                                   steps),
+                              transport=transport, grad_accum=grad_accum,
+                              attn_chunk=min(1024, seq))
+
+    @contextlib.contextmanager
+    def scope():
+        if mesh is None:
+            yield
+            return
         hint_axes, hint_size = transport.hint_axes(mesh)
-        with mesh, activation_sharding(hint_axes, hint_size,
-                                       PT.MODEL_AXIS, mp):
-            _run(state, step_fn, cfg, args, transport,
-                 state_shardings=shardings)
-    else:
-        opt = make_opt()
-        transport = TR.make_transport(wire=args.grad_wire,
-                                      wire_policy=wire_policy)
-        state = make_train_state(params, opt, transport=transport)
-        step_fn = make_train_step(cfg, policy, opt, lr_schedule,
-                                  transport=transport,
-                                  grad_accum=args.grad_accum,
-                                  attn_chunk=min(1024, args.seq))
-        _run(state, step_fn, cfg, args, transport)
+        with jax.set_mesh(mesh), activation_sharding(
+                hint_axes, hint_size, PT.MODEL_AXIS,
+                mesh.shape[PT.MODEL_AXIS]):
+            yield
+
+    return Training(state, jax.jit(step_fn, donate_argnums=(0,)), shardings,
+                    transport, scope)
 
 
-def _run(state, step_fn, cfg, args, transport, state_shardings=None):
+def _run(run: Training, cfg, args):
     def batches(start_step):
         # step-keyed stream: a resume (or spike rollback) at step k
         # continues with batch k — never replays batches 0..k-1
@@ -193,7 +231,7 @@ def _run(state, step_fn, cfg, args, transport, state_shardings=None):
                           start_step=start_step)
     log = print if MH.is_primary() else (lambda *_a, **_k: None)
     state, info = run_training(
-        state, jax.jit(step_fn), batches,
+        run.state, run.step, batches,
         TrainLoopConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
                         ckpt_every=args.ckpt_every, seed=args.seed,
                         async_saves=not args.sync_ckpt,
@@ -201,8 +239,9 @@ def _run(state, step_fn, cfg, args, transport, state_shardings=None):
                         spike_patience=args.spike_patience,
                         max_rollbacks=args.max_rollbacks,
                         preempt_poll_every=args.preempt_poll,
-                        wire_format=getattr(transport, "wire_format", None)),
-        log=log, state_shardings=state_shardings)
+                        wire_format=getattr(run.transport, "wire_format",
+                                            None)),
+        log=log, state_shardings=run.shardings)
     last = info["history"][-1] if info["history"] else {}
     log(f"[train] done at step {int(jax.device_get(state.step))}; "
         f"final loss {last.get('loss'):.4f}; "

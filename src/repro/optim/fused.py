@@ -25,15 +25,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro._compat import ensure_shard_map
 from repro.core.policy import PrecisionPolicy
 from repro.kernels.fused_adamw import fused_adamw
 from repro.kernels.fused_sgd import fused_sgd
 from repro.optim.adamw import AdamWState
 from repro.optim.base import Optimizer, state_ops
 from repro.optim.sgd import SGDState
-
-ensure_shard_map()
 
 __all__ = ["fused_sgd_optimizer", "fused_adamw_optimizer"]
 

@@ -36,13 +36,9 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro._compat import ensure_shard_map
 from repro.core.formats import (BF16, FloatFormat, clamp_finite,
                                 round_stochastic, stochastic_round_bf16,
                                 wire_carrier_dtype)
-
-# callers wrap compressed_psum in jax.shard_map; backfill it on older jax
-ensure_shard_map()
 
 __all__ = ["compress_leaf", "compressed_psum", "init_residual"]
 

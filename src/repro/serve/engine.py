@@ -504,7 +504,7 @@ class Engine:
             if self.mesh is not None:
                 args = {k: jax.device_put(v, self._in_shardings[k])
                         for k, v in args.items()}
-                ctx.enter_context(self.mesh)
+                ctx.enter_context(jax.set_mesh(self.mesh))
                 ctx.enter_context(activation_sharding(
                     self._dp, dp_size(self.mesh), "model", self._mp))
             step_fn = self._fn(width, need_logits)

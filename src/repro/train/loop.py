@@ -8,7 +8,9 @@ Wraps the jitted train step with the machinery a 1000-node run needs:
   every exit path — completion, preemption, crash — drains the writer
 * checkpoint-on-SIGTERM preemption hook (snapshot, drain, exit)
 * bounded retry around the step (transient-failure tolerance; a
-  fault-injection hook exists for tests)
+  fault-injection hook exists for tests). A step that donates its state
+  and fails after consuming it is not retried: the run raises and
+  restarts from its last checkpoint
 * a loss-spike / divergence monitor (``spike_factor``) that rolls the
   run back to the last good checkpoint and widens the checkpoint
   cadence, instead of checkpointing over it with poisoned state
@@ -199,6 +201,12 @@ def _restore(mgr: CheckpointManager, state: TrainState, state_shardings, log,
     return mgr.restore_latest(state, shardings=state_shardings, step=step)
 
 
+def _consumed(state: TrainState) -> bool:
+    """Whether a donating step has already deleted ``state``'s buffers."""
+    return any(isinstance(x, jax.Array) and x.is_deleted()
+               for x in jax.tree_util.tree_leaves(state))
+
+
 @dataclasses.dataclass
 class TrainLoopConfig:
     total_steps: int
@@ -351,6 +359,14 @@ def run_training(state: TrainState, train_step: Callable, batches: Batches,
                     break
                 except Exception as e:          # noqa: BLE001 — retry wall
                     attempt += 1
+                    if _consumed(state):
+                        # a step jitted with a donated state deletes its
+                        # input once it runs: there is nothing left to
+                        # retry from or to checkpoint
+                        log(f"[loop] step {step} failed after consuming "
+                            f"its donated state; raising for restart from "
+                            f"the last committed checkpoint: {e}")
+                        raise
                     if attempt > cfg.max_retries_per_step:
                         if mgr and not multiproc:
                             mgr.maybe_save(step, state, force=True)

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_local_mesh
 from repro.train import checkpoint as C
 
 
@@ -185,7 +186,7 @@ class TestElastic:
         from jax.sharding import NamedSharding, PartitionSpec as P
         t = _tree()
         C.save(tmp_path, 1, t)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_local_mesh(1)
         shardings = jax.tree_util.tree_map(
             lambda _: NamedSharding(mesh, P()), t)
         got, _ = C.restore(tmp_path, t, shardings=shardings)

@@ -28,6 +28,7 @@ def test_sharded_train_step_matches_single_device():
     """Same seed/data: 8-device (4 data × 2 model) step == 1-device step."""
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_local_mesh
         from repro.core import get_policy
         from repro.dist import partition as PT
         from repro.dist.axes import activation_sharding
@@ -50,13 +51,13 @@ def test_sharded_train_step_matches_single_device():
         s1b, m1 = jax.jit(step_fn)(s1, batch, 0)
 
         # 8 devices
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_local_mesh(4, 2)
         pspecs = PT.param_specs(params, cfg, mesh)
         pshard = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), pspecs,
                                         is_leaf=lambda x: hasattr(x, "_normalized_spec_for_aval"))
         params8 = jax.device_put(params, pshard)
         s8 = make_train_state(params8, opt)
-        with mesh, activation_sharding(("data",), 4, "model", 2):
+        with jax.set_mesh(mesh), activation_sharding(("data",), 4, "model", 2):
             s8b, m8 = jax.jit(step_fn)(s8, batch, 0)
         print("loss1", float(m1["loss"]), "loss8", float(m8["loss"]))
         d = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
@@ -76,10 +77,11 @@ def test_sharded_train_step_matches_single_device():
 def test_compressed_psum_unbiased_and_bf16_wire():
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_local_mesh
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.optim.grad_compress import compressed_psum, init_residual
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_local_mesh(8)
         g = {"w": jnp.linspace(-1, 1, 4096, dtype=jnp.float32)}
         res = init_residual(g)
 
@@ -106,6 +108,7 @@ def test_dryrun_small_mesh_compiles_train_and_decode():
     dry-run machinery beyond the big background sweep."""
     out = _run("""
         import jax
+        from repro.launch.mesh import make_local_mesh
         from repro.configs import base as CB
         small_train = CB.ShapeConfig("train_4k", 128, 8, "train")
         small_dec  = CB.ShapeConfig("decode_32k", 128, 8, "decode")
@@ -114,7 +117,7 @@ def test_dryrun_small_mesh_compiles_train_and_decode():
                                       "decode_32k": small_dec}.get(n) or orig(n)
         import repro.launch.dryrun as DR
         DR.shape_by_name = CB.shape_by_name
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_local_mesh(4, 2)
         for arch, shape in [("yi-9b", "train_4k"),
                             ("falcon-mamba-7b", "decode_32k"),
                             ("recurrentgemma-2b", "train_4k"),

@@ -14,6 +14,7 @@ from repro.core import get_policy
 from repro.dist import partition as PT
 from repro.dist.axes import (activation_sharding, current_sharding,
                              padded_head_count, shard_batch, shard_heads)
+from repro.launch.mesh import make_local_mesh
 from repro.models import registry as R
 from repro.optim import adamw, sgd
 
@@ -206,7 +207,7 @@ class TestBatchCacheSpecs:
                     jax.tree_util.tree_leaves(cspecs)):
                 assert len(spec) == len(leaf.shape), (arch, path, spec)
                 # stacked-layer caches carry batch in dim 1
-                assert spec[1] == ("data",), (arch, path, spec)
+                assert spec[1] == "data", (arch, path, spec)
                 for dim, axis in enumerate(spec):
                     if axis == "model":
                         assert leaf.shape[dim] % 2 == 0, (arch, path, spec)
@@ -251,8 +252,7 @@ class TestMultihostInit:
 class TestInProcessPlacement:
     def test_param_put_and_activation_constraints(self, eight_virtual_devices):
         from jax.sharding import NamedSharding
-        mesh = jax.make_mesh((4, 2), ("data", "model"),
-                             devices=eight_virtual_devices)
+        mesh = make_local_mesh(4, 2, devices=eight_virtual_devices)
         cfg = R.get_config("qwen2.5-3b").reduced()
         params = R.init(cfg, jax.random.PRNGKey(0), jnp.bfloat16)
         pspecs = PT.param_specs(params, cfg, mesh)
@@ -267,7 +267,7 @@ class TestInProcessPlacement:
             return shard_batch(shard_heads(x, 2))
 
         x = jnp.ones((8, 16, 4, 32))
-        with mesh, activation_sharding(("data",), 4, "model", 2):
+        with jax.set_mesh(mesh), activation_sharding(("data",), 4, "model", 2):
             y = f(x)
         assert y.sharding.spec[0] in ("data", ("data",))
         assert y.sharding.spec[2] == "model"

@@ -45,6 +45,10 @@ SMALL_EXP = ["fp16", "e5m2", "e4m3"]   # formats with their own subnormal range
 N_SAMPLES = 4096
 FIVE_SIGMA = 5.0
 
+def _f32(v: float) -> float:
+    """``v`` rounded to float32: ``st.floats(width=32)`` wants bounds it can hold exactly."""
+    return float(np.float32(v))
+
 
 def _key(*ints) -> jax.Array:
     k = jax.random.PRNGKey(20240808)
@@ -64,7 +68,7 @@ class TestStochasticUnbiased:
     # idiom as test_formats.py::test_hyp_monotonic_grid).
     @settings(max_examples=40, deadline=None)
     @given(st.sampled_from(GRID),
-           st.floats(min_value=0.03, max_value=0.47, width=32))
+           st.floats(min_value=_f32(0.03), max_value=_f32(0.47), width=32))
     def test_unbiased_at_sub_ulp_offsets(self, fname, theta):
         """x = 1 + θ·ulp with θ < 1/2: nearest stalls at 1.0 forever;
         SR must average back to x (binomial mean within 5σ)."""
@@ -88,7 +92,7 @@ class TestStochasticUnbiased:
 
     @settings(max_examples=24, deadline=None)
     @given(st.sampled_from(SMALL_EXP),
-           st.floats(min_value=0.06, max_value=0.94, width=32))
+           st.floats(min_value=_f32(0.06), max_value=_f32(0.94), width=32))
     def test_unbiased_on_subnormal_grid(self, fname, theta):
         """θ·sub_spacing (below min_normal, where the format's own
         subnormal lattice rules): SR splits between 0 and sub_spacing
@@ -113,7 +117,7 @@ class TestStochasticUnbiased:
 class TestIdempotence:
     @settings(max_examples=120, deadline=None)
     @given(st.sampled_from(GRID),
-           st.floats(min_value=-3e38, max_value=3e38, width=32))
+           st.floats(min_value=_f32(-3e38), max_value=_f32(3e38), width=32))
     def test_round_nearest_idempotent(self, fname, x):
         fmt = FORMATS[fname]
         y = round_nearest(jnp.float32(x), fmt)
@@ -122,7 +126,7 @@ class TestIdempotence:
 
     @settings(max_examples=120, deadline=None)
     @given(st.sampled_from(GRID),
-           st.floats(min_value=-3e38, max_value=3e38, width=32),
+           st.floats(min_value=_f32(-3e38), max_value=_f32(3e38), width=32),
            st.integers(min_value=0, max_value=2 ** 30))
     def test_round_stochastic_fixes_grid_points(self, fname, x, seed):
         """A grid point is a fixed point of SR for every key."""
@@ -207,7 +211,7 @@ class TestUlp:
 class TestOverflow:
     @settings(max_examples=50, deadline=None)
     @given(st.sampled_from(["e5m2", "e4m3"]),
-           st.floats(min_value=1.0, max_value=3e38, width=32),
+           st.floats(min_value=1.0, max_value=_f32(3e38), width=32),
            st.integers(min_value=0, max_value=2 ** 30))
     def test_no_inf_escapes_small_exp(self, fname, x, seed):
         """Every finite (or infinite) input maps to a finite grid value

@@ -171,9 +171,9 @@ class TestMeshValidation:
     def test_unknown_axis_rejected(self):
         from repro.launch import mesh as LM
         with pytest.raises(ValueError, match="unknown mesh axis"):
-            LM._validated_mesh((1,), ("bogus",))
+            LM.make_mesh((1,), ("bogus",))
         with pytest.raises(ValueError, match="duplicate"):
-            LM._validated_mesh((1, 1), ("data", "data"))
+            LM.make_mesh((1, 1), ("data", "data"))
 
     def test_production_fsdp_must_divide(self):
         from repro.launch.mesh import make_production_mesh
@@ -243,7 +243,7 @@ def test_fsdp_step_matches_single_device_and_halves_memory():
 
         step8 = make_fsdp_train_step(cfg, policy, opt, constant(1e-3),
                                      pspecs=pspecs, placement=pl, attn_chunk=8)
-        with mesh, activation_sharding(PT.dp_axes(mesh), PT.dp_size(mesh),
+        with jax.set_mesh(mesh), activation_sharding(PT.dp_axes(mesh), PT.dp_size(mesh),
                                        "model", 2):
             s8b, m8 = jax.jit(step8)(s8, batch, 0)
         print("loss1", float(m1["loss"]), "loss8", float(m8["loss"]))
@@ -372,7 +372,7 @@ def test_grad_accum_keeps_working_copy_gather_out_of_the_scan():
             step = make_fsdp_train_step(cfg, policy, opt, constant(1e-3),
                                         pspecs=pspecs, placement=pl,
                                         attn_chunk=8, grad_accum=ga)
-            with mesh, activation_sharding(PT.dp_axes(mesh),
+            with jax.set_mesh(mesh), activation_sharding(PT.dp_axes(mesh),
                                            PT.dp_size(mesh), "model", 2):
                 text = jax.jit(step).lower(state, batch, 0).compile().as_text()
             c = analyze_hlo(text)

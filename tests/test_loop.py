@@ -74,6 +74,35 @@ def test_persistent_failure_checkpoints_and_raises(tmp_path):
     assert C.latest_step(tmp_path) == 2       # crash checkpoint exists
 
 
+def test_failure_after_donation_raises_without_retry(tmp_path):
+    """A donating step that fails after it consumed its state leaves
+    nothing to retry from or to checkpoint: the loop raises at once."""
+    state, _ = _setup()
+    state = jax.tree_util.tree_map(jnp.copy, state)   # one buffer per leaf
+    opt = adamw(POLICY, b2=0.997)
+    donating = jax.jit(make_train_step(CFG, POLICY, opt, constant(1e-3),
+                                       attn_chunk=8), donate_argnums=(0,))
+    calls = {"n": 0}
+
+    def step(state, batch, seed):
+        out = donating(state, batch, seed)
+        if int(jax.device_get(out[0].step)) == 3:
+            calls["n"] += 1
+            raise RuntimeError("device fault after dispatch")
+        return out
+
+    logs = []
+    with pytest.raises(RuntimeError, match="after dispatch"):
+        run_training(state, step, lm_batches(CFG.vocab, 4, 16),
+                     TrainLoopConfig(total_steps=6, ckpt_dir=str(tmp_path),
+                                     ckpt_every=100, max_retries_per_step=2),
+                     log=logs.append)
+    assert calls["n"] == 1                    # not retried
+    assert any("consuming its donated state" in m for m in logs), logs
+    from repro.train import checkpoint as C
+    assert C.latest_step(tmp_path) is None    # no crash save of dead buffers
+
+
 def test_resume_is_exact(tmp_path):
     """10 straight steps ≡ 5 steps + checkpoint + resume + 5 steps,
     bit-for-bit (deterministic data + per-step keys)."""

@@ -66,8 +66,9 @@ def test_two_process_gloo_collectives(tmp_path):
         "assert MH.initialize(), 'REPRO_* env missing'\n"
         "import jax, jax.numpy as jnp\n"
         "from jax.sharding import NamedSharding, PartitionSpec as P\n"
+        "from repro.launch.mesh import make_local_mesh\n"
         "assert jax.device_count() == 2 and jax.local_device_count() == 1\n"
-        "mesh = jax.make_mesh((2,), ('data',))\n"
+        "mesh = make_local_mesh(2)\n"
         "x = jax.device_put(jnp.arange(4.0), NamedSharding(mesh, P('data')))\n"
         "print('sum', float(jax.jit(lambda a: a.sum())(x)))\n"
         "MH.barrier('done')\n"

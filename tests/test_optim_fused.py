@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import get_policy
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw, sgd
 from repro.optim.fused import fused_adamw_optimizer, fused_sgd_optimizer
 
@@ -113,7 +114,7 @@ class TestShardLocal:
                                         jnp.bfloat16),
                  "b": jax.random.normal(jax.random.PRNGKey(3), (32,),
                                         jnp.bfloat16)}
-        mesh = jax.make_mesh((8,), ("fsdp",))
+        mesh = make_mesh((8,), ("fsdp",))
         pspecs = {"w": P("fsdp", None), "b": P("fsdp")}
         sharded = {k: jax.device_put(v, NamedSharding(mesh, pspecs[k]))
                    for k, v in params.items()}
@@ -132,7 +133,7 @@ class TestShardLocal:
         key = jax.random.PRNGKey(4)
         pg, _ = g_opt.update(grads, g_opt.init(params), params,
                              step=0, key=key, lr=1e-3)
-        with mesh:
+        with jax.set_mesh(mesh):
             pl_, _ = l_opt.update(gsharded, l_opt.init(sharded), sharded,
                                   step=0, key=key, lr=1e-3)
         for k in params:
@@ -147,7 +148,7 @@ class TestShardLocal:
                                     pspecs=pspecs)
         g_opt = fused_sgd_optimizer(policy, momentum=0.9)
         key = jax.random.PRNGKey(5)
-        with mesh:
+        with jax.set_mesh(mesh):
             a, _ = l_opt.update(gsharded, l_opt.init(sharded), sharded,
                                 step=0, key=key, lr=1e-2)
             b, _ = l_opt.update(gsharded, l_opt.init(sharded), sharded,

@@ -21,6 +21,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import get_policy
 from repro.dist import partition as PT
+from repro.launch.mesh import make_local_mesh
 from repro.models import registry as R
 from repro.serve import CachePool, Engine, PagedCachePool, generate, sampling
 from repro.serve.cache import (cache_dtype, keep_active, reset_pages,
@@ -286,7 +287,7 @@ class TestShardedEngine:
         gens = (6, 8, 6, 8, 6, 8, 6, 8, 6, 8)
         prompts = _prompts(rng, sizes, cfg.vocab)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_local_mesh(4, 2)
         pspecs = PT.param_specs(params, cfg, mesh)
         params8 = jax.device_put(params, jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), pspecs,
@@ -294,7 +295,7 @@ class TestShardedEngine:
         eng = Engine(params8, cfg, NEAREST, n_slots=8, max_len=24, mesh=mesh)
         # the slot axis of every KV leaf is sharded over the data axis
         k = eng.pool.cache["layers"]["b0"][0]
-        assert k.sharding.spec[1] == ("data",)      # dim 1: stacked layers
+        assert k.sharding.spec[1] == "data"         # dim 1: stacked layers
         assert "model" in jax.tree_util.tree_flatten(
             tuple(k.sharding.spec))[0]              # head dim on model
         for p, g in zip(prompts, gens):
@@ -549,7 +550,7 @@ class TestShardedPagedEngine:
         gens = (6, 8, 6, 8, 6, 8, 6, 8, 6, 8)
         prompts = _prompts(rng, sizes, cfg.vocab)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_local_mesh(4, 2)
         pspecs = PT.param_specs(params, cfg, mesh)
         params8 = jax.device_put(params, jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), pspecs,
@@ -559,7 +560,7 @@ class TestShardedPagedEngine:
                      prefill_chunk=4)
         assert eng.pool.n_rows % 4 == 0            # padded for the dp axes
         kp = eng.pool.cache["layers"]["b0"]["k_pages"]
-        assert kp.sharding.spec[1] == ("data",)    # page rows on data
+        assert kp.sharding.spec[1] == "data"       # page rows on data
         assert "model" in jax.tree_util.tree_flatten(
             tuple(kp.sharding.spec))[0]            # head dim on model
         for p, g in zip(prompts, gens):
@@ -587,7 +588,7 @@ class TestShardedFusedDecode:
         gens = (6, 8, 6, 8, 6, 8, 6, 8, 6, 8)
         prompts = _prompts(rng, sizes, cfg.vocab)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_local_mesh(4, 2)
         pspecs = PT.param_specs(params, cfg, mesh)
         params8 = jax.device_put(params, jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), pspecs,

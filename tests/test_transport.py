@@ -52,7 +52,8 @@ class _SpecMesh:
 class TestErrorFeedback:
     @settings(max_examples=40, deadline=None)
     @given(st.sampled_from(["bf16", "bf14", "bf12", "e5m2", "e4m3"]),
-           st.floats(min_value=0.01, max_value=100.0, width=32),
+           st.floats(min_value=float(np.float32(0.01)), max_value=100.0,
+                     width=32),
            st.integers(min_value=0, max_value=2**31 - 1))
     def test_residuals_telescope(self, fname, scale, seed):
         """Σ_t q_t == Σ_t g_t − r_T, for EVERY wire format: the quantized
@@ -593,7 +594,7 @@ def test_two_pod_wires_match_single_device():
                                    attn_chunk=8, transport=tr,
                                    grad_accum=accum)
             hints, hsize = tr.hint_axes(mesh)
-            with mesh, activation_sharding(hints, hsize, "model", 2):
+            with jax.set_mesh(mesh), activation_sharding(hints, hsize, "model", 2):
                 sb, m = jax.jit(step)(state, batch, 0)
             d = max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                           - b.astype(jnp.float32))))

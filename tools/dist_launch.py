@@ -49,6 +49,7 @@ def launch(argv: list[str], nprocs: int, *, devices_per_proc: int = 1,
     coordinator = coordinator or f"127.0.0.1:{free_port()}"
     base = dict(os.environ if env is None else env)
     base.pop("XLA_FLAGS", None)
+    base["JAX_PLATFORMS"] = "cpu"       # loopback gloo ranks; never the chip
     base["JAX_NUM_CPU_DEVICES"] = str(devices_per_proc)
     base["REPRO_COORDINATOR"] = coordinator
     base["REPRO_NUM_PROCESSES"] = str(nprocs)
