@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.profile import scopes
+
 __all__ = ["fused_adamw", "fused_adamw_kernel"]
 
 LANE = 128
@@ -80,6 +82,7 @@ def fused_adamw_kernel(w_ref, m_ref, v_ref, g_ref, c_ref, bits_ref,
     c_out[...] = c_new
 
 
+@jax.named_scope(scopes.LAYOUT)
 def _pad2(x, rows, cols, dtype):
     flat = jnp.ravel(x).astype(dtype)
     total = rows * cols
@@ -121,8 +124,10 @@ def fused_adamw(w, m, v, g, *, c=None, bits=None, lr, b1, b2, eps, wd,
         out_specs=[bs, bs, bs, bs],
         out_shape=[out_sds, out_sds, out_sds, out_sds],
         interpret=interpret,
+        name=scopes.FUSED_ADAMW,
     )(wp, mp, vp, gp, cp, bp, scalars)
 
+    @jax.named_scope(scopes.LAYOUT)
     def unpad(a):
         return a.reshape(-1)[:n].reshape(w.shape)
     return unpad(w2), unpad(m2), unpad(v2), (unpad(c2_) if kahan else None)

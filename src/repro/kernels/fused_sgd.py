@@ -12,6 +12,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_adamw import _pad2, _sr_to_bf16, BLOCK_ROWS, LANE
+from repro.profile import scopes
 
 __all__ = ["fused_sgd", "fused_sgd_kernel"]
 
@@ -72,8 +73,10 @@ def fused_sgd(w, m, g, *, c=None, bits=None, lr, momentum=0.9, wd=0.0,
         out_specs=[bs, bs, bs],
         out_shape=[out_sds, out_sds, out_sds],
         interpret=interpret,
+        name=scopes.FUSED_SGD,
     )(wp, mp, gp, cp, bp, scalars)
 
+    @jax.named_scope(scopes.LAYOUT)
     def unpad(a):
         return a.reshape(-1)[:n].reshape(w.shape)
     return unpad(w2), unpad(m2), (unpad(c2) if kahan else None)

@@ -21,6 +21,7 @@ from repro.models import layers as L
 from repro.models import moe as M
 from repro.models import rglru as R
 from repro.models import ssm as S
+from repro.profile import scopes
 
 __all__ = ["init_lm", "forward", "init_cache", "decode_step"]
 
@@ -61,7 +62,8 @@ def block_apply(qa: QArith, cfg, kind: str, p, x, *, positions,
                 cache=None, cache_pos=None, mrope_positions=None,
                 attn_chunk: int = 1024, block_table=None):
     """Returns (x, new_cache). cache=None for full-sequence (train/prefill)."""
-    h = L.norm_apply(qa, cfg.norm, p["ln1"], x)
+    with jax.named_scope(scopes.NORM):
+        h = L.norm_apply(qa, cfg.norm, p["ln1"], x)
     new_cache = None
     if kind == "mamba":
         if cache is None:
@@ -85,17 +87,20 @@ def block_apply(qa: QArith, cfg, kind: str, p, x, *, positions,
     else:
         window = (cfg.local_attn_window if kind == "local_attn"
                   else cfg.swa_window)
-        y, new_cache = L.attention_apply(
-            qa, p["mixer"], h, cfg, positions=positions, causal=True,
-            window=window, cache=cache, cache_pos=cache_pos,
-            chunk=attn_chunk, mrope_positions=mrope_positions,
-            block_table=block_table)
+        with jax.named_scope(scopes.ATTENTION):
+            y, new_cache = L.attention_apply(
+                qa, p["mixer"], h, cfg, positions=positions, causal=True,
+                window=window, cache=cache, cache_pos=cache_pos,
+                chunk=attn_chunk, mrope_positions=mrope_positions,
+                block_table=block_table)
     x = qa.add(x, y)
-    h = L.norm_apply(qa, cfg.norm, p["ln2"], x)
-    if kind == "moe":
-        y = M.moe_apply(qa, p["ffn"], h, cfg)
-    else:
-        y = M.mlp_apply(qa, p["ffn"], h, cfg.act_fn)
+    with jax.named_scope(scopes.NORM):
+        h = L.norm_apply(qa, cfg.norm, p["ln2"], x)
+    with jax.named_scope(scopes.MLP):
+        if kind == "moe":
+            y = M.moe_apply(qa, p["ffn"], h, cfg)
+        else:
+            y = M.mlp_apply(qa, p["ffn"], h, cfg.act_fn)
     return qa.add(x, y), new_cache
 
 
@@ -193,6 +198,7 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16, *,
 # Forward (train / prefill) and decode
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(scopes.EMBED)
 def _embed_tokens(qa, cfg, params, tokens_or_embeds):
     if tokens_or_embeds.dtype in (jnp.int32, jnp.int64):
         x = jnp.take(params["embed"]["embedding"], tokens_or_embeds, axis=0)
@@ -205,6 +211,7 @@ def _embed_tokens(qa, cfg, params, tokens_or_embeds):
     return x
 
 
+@jax.named_scope(scopes.HEAD)
 def _logits(qa, cfg, params, x):
     h = L.norm_apply(qa, cfg.norm, params["final_norm"], x)
     if cfg.tie_embeddings:

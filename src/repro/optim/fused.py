@@ -31,6 +31,7 @@ from repro.kernels.fused_sgd import fused_sgd
 from repro.optim.adamw import AdamWState
 from repro.optim.base import Optimizer, state_ops
 from repro.optim.sgd import SGDState
+from repro.profile import scopes
 
 __all__ = ["fused_sgd_optimizer", "fused_adamw_optimizer"]
 
@@ -42,6 +43,12 @@ def _check(policy: PrecisionPolicy):
         raise ValueError(
             f"fused kernels implement the bf16 16-bit-FPU recipe; "
             f"policy {policy.name!r} is not supported")
+
+
+@jax.named_scope(scopes.SR_BITS)
+def _sr_bits(key, w):
+    """32 random bits per element of ``w``, for stochastic rounding."""
+    return jax.random.bits(key, shape=w.shape, dtype=jnp.uint32)
 
 
 def _spec_axes(spec: P) -> tuple[str, ...]:
@@ -80,7 +87,8 @@ def _shard_local_update(leaf_update, mesh, pspecs, treedef, n_state: int):
     specs_l = treedef.flatten_up_to(pspecs)
 
     def run(w_l, g_l, state_ls, key, scalars):
-        keys = list(jax.random.split(key, len(w_l)))
+        with jax.named_scope(scopes.SR_BITS):
+            keys = list(jax.random.split(key, len(w_l)))
 
         def body(w_l, g_l, state_ls, keys, scalars):
             folded = [_shard_key(k, s, mesh) for k, s in zip(keys, specs_l)]
@@ -118,8 +126,7 @@ def fused_sgd_optimizer(policy: PrecisionPolicy, *, momentum: float = 0.9,
         c_l = state_ls[1] if policy.kahan else [None] * len(w_l)
         new_w, new_m, new_c = [], [], []
         for w, g, m, c, k in zip(w_l, g_l, m_l, c_l, keys):
-            bits = (jax.random.bits(k, shape=w.shape, dtype=jnp.uint32)
-                    if stochastic else None)
+            bits = _sr_bits(k, w) if stochastic else None
             w2, m2, c2 = fused_sgd(
                 w, m, g.astype(jnp.bfloat16), c=c, bits=bits,
                 stochastic=stochastic, lr=lr, momentum=momentum,
@@ -143,7 +150,8 @@ def fused_sgd_optimizer(policy: PrecisionPolicy, *, momentum: float = 0.9,
                                       len(state_ls))
             out = run(leaves, g_l, state_ls, key, (lr,))
         else:
-            keys = list(jax.random.split(key, len(leaves)))
+            with jax.named_scope(scopes.SR_BITS):
+                keys = list(jax.random.split(key, len(leaves)))
             out = leaf_update(leaves, g_l, state_ls, keys, (lr,))
         unf = lambda ls: jax.tree_util.tree_unflatten(treedef, ls)
         new_w, new_m = out[0], out[1]
@@ -179,8 +187,7 @@ def fused_adamw_optimizer(policy: PrecisionPolicy, *, b1: float = 0.9,
         c_l = state_ls[2] if policy.kahan else [None] * len(w_l)
         new_w, new_m, new_v, new_c = [], [], [], []
         for w, g, m, v, c, k in zip(w_l, g_l, m_l, v_l, c_l, keys):
-            bits = (jax.random.bits(k, shape=w.shape, dtype=jnp.uint32)
-                    if stochastic else None)
+            bits = _sr_bits(k, w) if stochastic else None
             w2, m2, v2, c2_ = fused_adamw(
                 w, m, v, g.astype(jnp.bfloat16), c=c, bits=bits,
                 stochastic=stochastic, lr=lr, b1=b1q, b2=b2q, eps=eps,
@@ -208,7 +215,8 @@ def fused_adamw_optimizer(policy: PrecisionPolicy, *, b1: float = 0.9,
                                       len(state_ls))
             out = run(leaves, g_l, state_ls, key, scalars)
         else:
-            keys = list(jax.random.split(key, len(leaves)))
+            with jax.named_scope(scopes.SR_BITS):
+                keys = list(jax.random.split(key, len(leaves)))
             out = leaf_update(leaves, g_l, state_ls, keys, scalars)
         unf = lambda ls: jax.tree_util.tree_unflatten(treedef, ls)
         new_w, new_m, new_v = out[0], out[1], out[2]

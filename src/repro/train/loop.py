@@ -41,7 +41,9 @@ import time
 from typing import Any, Callable, Iterator, Union
 
 import jax
+from jax.profiler import TraceAnnotation
 
+from repro.profile import scopes
 from repro.train.checkpoint import CheckpointManager, latest_step, manifest
 from repro.train.train_state import TrainState
 
@@ -326,7 +328,8 @@ def run_training(state: TrainState, train_step: Callable, batches: Batches,
         # one host sync for a whole window of rows, instead of one
         # device_get per metric per step
         if pending:
-            fetched = jax.device_get(pending)
+            with TraceAnnotation(scopes.SPAN_FLUSH):
+                fetched = jax.device_get(pending)
             del pending[:]
             metrics_hist.extend(
                 {k: float(v) for k, v in row.items()} for row in fetched)
@@ -342,7 +345,8 @@ def run_training(state: TrainState, train_step: Callable, batches: Batches,
     rollbacks = 0
     try:
         while step < cfg.total_steps:
-            batch = next(stream)
+            with TraceAnnotation(scopes.SPAN_BATCH):
+                batch = next(stream)
             t0 = time.time()
             attempt = 0
             while True:
@@ -353,8 +357,11 @@ def run_training(state: TrainState, train_step: Callable, batches: Batches,
                     # async dispatch a device fault surfaces at block_until_ready,
                     # and retries (and the crash checkpoint) must see the last
                     # good state, not the failed step's poisoned buffers
-                    new_state, metrics = train_step(state, batch, cfg.seed)
-                    jax.block_until_ready(metrics["loss"])
+                    with TraceAnnotation(scopes.SPAN_DISPATCH):
+                        new_state, metrics = train_step(state, batch,
+                                                        cfg.seed)
+                    with TraceAnnotation(scopes.SPAN_SYNC):
+                        jax.block_until_ready(metrics["loss"])
                     state = new_state
                     break
                 except Exception as e:          # noqa: BLE001 — retry wall
@@ -461,7 +468,8 @@ def run_training(state: TrainState, train_step: Callable, batches: Batches,
                     # across processes (snapshots are collective)
                     mgr.every_steps = max(
                         base // (2 if stragglers > 3 else 1), 1)
-                mgr.maybe_save(step + 1, state)
+                with TraceAnnotation(scopes.SPAN_CHECKPOINT):
+                    mgr.maybe_save(step + 1, state)
             if spike_run > 0:
                 # quarantine: if the run rolls back, the trajectory this
                 # row measured is discarded — it must not reach history
@@ -485,7 +493,8 @@ def run_training(state: TrainState, train_step: Callable, batches: Batches,
                     or step % max(cfg.preempt_poll_every, 1) == 0)
             if poll and _agree_preempted(stop["preempted"], multiproc):
                 if mgr:
-                    mgr.maybe_save(step + 1, state, force=True)
+                    with TraceAnnotation(scopes.SPAN_CHECKPOINT):
+                        mgr.maybe_save(step + 1, state, force=True)
                 log(f"[loop] preempted at step {step}; checkpointed and exiting")
                 break
             step += 1
@@ -507,6 +516,7 @@ def run_training(state: TrainState, train_step: Callable, batches: Batches,
         suspect.clear()
     _flush()
     if mgr:
-        mgr.drain()             # preemption/final saves committed before return
+        with TraceAnnotation(scopes.SPAN_CHECKPOINT):
+            mgr.drain()         # preemption/final saves committed before return
     return state, {"history": metrics_hist, "stragglers": stragglers,
                    "preempted": stop["preempted"], "rollbacks": rollbacks}

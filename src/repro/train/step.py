@@ -19,6 +19,11 @@ single reduce + optimizer update.
 
 ``make_fsdp_train_step`` is a thin delegation that selects the
 reduce-scatter transport from ``pspecs``/``placement``.
+
+Each part of the step runs under a ``jax.named_scope`` named in
+:mod:`repro.profile.scopes` (``model``, ``transport``, ``optimizer``,
+``step_metrics``), so a device trace can say where a step's time goes
+(``docs/profiling.md``).
 """
 from __future__ import annotations
 
@@ -33,6 +38,7 @@ from repro.core.qarith import QArith
 from repro.dist import transport as T
 from repro.dist.partition import Placement
 from repro.models import registry as R
+from repro.profile import scopes as S
 from repro.train.train_state import TrainState, softmax_xent
 
 __all__ = ["make_train_step", "make_fsdp_train_step", "make_eval_step",
@@ -129,11 +135,13 @@ def make_train_step(cfg, policy: PrecisionPolicy, optimizer, lr_schedule,
     n_wire = transport.wire_replicas
 
     def _loss(params, batch):
-        logits = R.forward_logits(qa, params, cfg, batch, remat=remat,
-                                  attn_chunk=attn_chunk)
-        if loss_fn is not None:
-            return loss_fn(logits, batch)
-        return softmax_xent(logits, batch["labels"])
+        with jax.named_scope(S.MODEL):
+            logits = R.forward_logits(qa, params, cfg, batch, remat=remat,
+                                      attn_chunk=attn_chunk)
+            with jax.named_scope(S.LOSS):
+                if loss_fn is not None:
+                    return loss_fn(logits, batch)
+                return softmax_xent(logits, batch["labels"])
 
     def _micro_grads(wc, batch):
         """Loss + grads of one microbatch; grads stacked (n_wire, ...)
@@ -152,7 +160,8 @@ def make_train_step(cfg, policy: PrecisionPolicy, optimizer, lr_schedule,
         wire_key = jax.random.fold_in(key, 7)
         # local-shard cast, then the transport's pre-forward placement
         # (FSDP: the bf16-wire all-gather of the working copy)
-        wc = transport.prepare(compute_params(state.params, policy))
+        with jax.named_scope(S.TRANSPORT):
+            wc = transport.prepare(compute_params(state.params, policy))
         if grad_accum > 1:
             # one-gather-per-step contract: the gathered working copy is
             # formed here, outside the microbatch scan, and closed over
@@ -170,8 +179,9 @@ def make_train_step(cfg, policy: PrecisionPolicy, optimizer, lr_schedule,
             def body(carry, mb):
                 acc_loss, acc = carry
                 loss, grads = _micro_grads(wc, mb)
-                acc = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(jnp.float32), acc, grads)
+                with jax.named_scope(S.TRANSPORT):
+                    acc = jax.tree_util.tree_map(
+                        lambda a, g: a + g.astype(jnp.float32), acc, grads)
                 return (acc_loss + loss, acc), None
 
             init = (jnp.zeros((), jnp.float32),
@@ -179,19 +189,24 @@ def make_train_step(cfg, policy: PrecisionPolicy, optimizer, lr_schedule,
                         lambda s: jnp.zeros(s.shape, jnp.float32), g_shape))
             (loss, grads), _ = jax.lax.scan(body, init, mbs)
             loss = loss / grad_accum
-            grads = jax.tree_util.tree_map(lambda g: g / grad_accum, grads)
+            with jax.named_scope(S.TRANSPORT):
+                grads = jax.tree_util.tree_map(lambda g: g / grad_accum,
+                                               grads)
         else:
             loss, grads = _micro_grads(wc, batch)
         # grads arrive in the compute dtype (bf16 FMAC outputs; f32 once
         # accumulated); the transport reduces them across replicas and
         # the quantized optimizer consumes them per Algorithms 2–5.
-        grads, new_residuals = transport.reduce(
-            grads, state.wire_residuals, wire_key)
+        with jax.named_scope(S.TRANSPORT):
+            grads, new_residuals = transport.reduce(
+                grads, state.wire_residuals, wire_key)
         lr = lr_schedule(state.step)
-        new_params, new_opt = optimizer.update(
-            grads, state.opt_state, state.params,
-            step=state.step, key=key, lr=lr)
-        new_params = transport.finalize(new_params)
+        with jax.named_scope(S.OPTIMIZER):
+            new_params, new_opt = optimizer.update(
+                grads, state.opt_state, state.params,
+                step=state.step, key=key, lr=lr)
+        with jax.named_scope(S.TRANSPORT):
+            new_params = transport.finalize(new_params)
         metrics = {"loss": loss.astype(jnp.float32), "lr": lr,
                    "grad_norm": _global_norm(grads)}
         return TrainState(state.step + 1, new_params, new_opt,
@@ -237,6 +252,7 @@ def make_fsdp_train_step(cfg, policy: PrecisionPolicy, optimizer, lr_schedule,
                            transport=transport, grad_accum=grad_accum)
 
 
+@jax.named_scope(S.STEP_METRICS)
 def _global_norm(tree: PyTree) -> jax.Array:
     leaves = jax.tree_util.tree_leaves(tree)
     return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
