@@ -9,11 +9,21 @@ HLO ops of Alg. 4); the fusion removes that traffic (see
 benchmarks/bench_kernels.py).
 
 Variants (compile-time flags): update_rounding ∈ {nearest, stochastic},
-kahan ∈ {off, on}. All tensors bf16 except the f32 scalars (lr, betas,
-eps, wd, bias corrections), which ride in SMEM as one (1, 7) array.
+kahan ∈ {off, on}. All tensors bf16 except the uint32 SR bits and the
+f32 scalars (lr, betas, eps, wd, bias corrections), which ride in SMEM
+as one (1, 7) array.
+
+Layout (:func:`tiling`, shared with ``fused_sgd``): the kernel is
+elementwise, so a leaf of rank ≥ 2 whose last dim is a multiple of 128
+(every matrix of the dense models: embedding, head, the stacked
+projections, the stacked norm scales) is tiled where it lies, with no
+copy. Only the others (rank-1 leaves, or a last dim such as 5 or 16)
+are raveled and padded into ``(rows, 128)`` tiles and sliced back,
+under the scope ``layout``.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -23,10 +33,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.profile import scopes
 
-__all__ = ["fused_adamw", "fused_adamw_kernel"]
+__all__ = ["fused_adamw", "fused_adamw_kernel", "tiling", "update_call"]
 
 LANE = 128
-BLOCK_ROWS = 256
+BLOCK_ROWS = 256        # flat path: (256, 128) tiles
+BLOCK = (256, 1024)     # native path: the largest block over the last two dims
 
 
 def _sr_to_bf16(val_f32, bits):
@@ -82,52 +93,91 @@ def fused_adamw_kernel(w_ref, m_ref, v_ref, g_ref, c_ref, bits_ref,
     c_out[...] = c_new
 
 
+def tiling(shape, block_rows=BLOCK_ROWS):
+    """How an elementwise update kernel tiles a leaf of ``shape``.
+
+    Returns ``(native, kernel_shape, block_shape, grid)``. A leaf of rank
+    2 or more whose last dim is a multiple of 128 is ``native``: the
+    kernel runs on it where it lies, in blocks of up to ``BLOCK`` over
+    its last two dims (leading dims squeezed, one per grid step; blocks
+    at the far edges ragged). Any other leaf is raveled and padded into
+    ``(rows, 128)`` tiles of ``block_rows`` rows.
+    """
+    if len(shape) >= 2 and shape[-1] % LANE == 0:
+        rows, cols = shape[-2:]
+        br, bc = min(BLOCK[0], rows), min(BLOCK[1], cols)
+        lead = tuple(shape[:-2])
+        return (True, tuple(shape), (pl.squeezed,) * len(lead) + (br, bc),
+                lead + (-(-rows // br), -(-cols // bc)))
+    rows = max(1, -(-math.prod(shape) // LANE))
+    grid_rows = -(-rows // block_rows) * block_rows
+    return (False, (grid_rows, LANE), (block_rows, LANE),
+            (grid_rows // block_rows,))
+
+
 @jax.named_scope(scopes.LAYOUT)
-def _pad2(x, rows, cols, dtype):
-    flat = jnp.ravel(x).astype(dtype)
-    total = rows * cols
+def _pad2(x, shape2):
+    flat = jnp.ravel(x)
+    total = math.prod(shape2)
     if total != flat.size:
         flat = jnp.pad(flat, (0, total - flat.size))
-    return flat.reshape(rows, cols)
+    return flat.reshape(shape2)
+
+
+def update_call(kernel, operands, scalars, aliases, *, name, interpret,
+                block_rows=BLOCK_ROWS):
+    """Run the elementwise update ``kernel`` over ``operands``, arrays of
+    one leaf's shape in the kernel's dtypes, with the f32 ``scalars`` as
+    one ``(1, k)`` array in SMEM. Output ``j`` is bf16 and overwrites
+    operand ``aliases[j]`` in place, so a donated state is updated where
+    it lies (without the alias XLA copies the state to free its buffer
+    for the step's output). One ``pallas_call`` named ``name`` per leaf;
+    only a leaf that :func:`tiling` does not take natively is copied into
+    tiles and back (scope ``layout``)."""
+    shape = operands[0].shape
+    native, kshape, bshape, grid = tiling(shape, block_rows)
+    if not native:
+        operands = [_pad2(x, kshape) for x in operands]
+    bs = pl.BlockSpec(bshape, (lambda *i: i) if native
+                      else (lambda i: (i, 0)))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out_sds = jax.ShapeDtypeStruct(kshape, jnp.bfloat16)
+    outs = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[bs] * len(operands) + [smem],
+        out_specs=[bs] * len(aliases),
+        out_shape=[out_sds] * len(aliases),
+        input_output_aliases={i: j for j, i in enumerate(aliases)},
+        interpret=interpret,
+        name=name,
+    )(*operands, scalars)
+    if native:
+        return outs
+    n = math.prod(shape)
+    with jax.named_scope(scopes.LAYOUT):
+        return [a.reshape(-1)[:n].reshape(shape) for a in outs]
 
 
 def fused_adamw(w, m, v, g, *, c=None, bits=None, lr, b1, b2, eps, wd,
                 c1, c2, stochastic: bool = True,
                 interpret: bool | None = None, block_rows: int = BLOCK_ROWS):
-    """One fused AdamW step on a flattened tensor. Returns (w', m', v', c').
+    """One fused AdamW step on one leaf. Returns (w', m', v', c').
 
     c (Kahan) and bits (SR) are optional; pass both for SR+Kahan (Fig 11).
+    The leaf is tiled as :func:`tiling` says.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     kahan = c is not None
-    n = w.size
-    rows = max(1, -(-n // LANE))
-    grid_rows = -(-rows // block_rows) * block_rows
-    shape2 = (grid_rows, LANE)
-    wp = _pad2(w, *shape2, jnp.bfloat16)
-    mp = _pad2(m, *shape2, jnp.bfloat16)
-    vp = _pad2(v, *shape2, jnp.bfloat16)
-    gp = _pad2(g, *shape2, jnp.bfloat16)
-    cp = _pad2(c if kahan else jnp.zeros_like(w), *shape2, jnp.bfloat16)
-    bp = _pad2(bits if bits is not None else jnp.zeros(w.shape, jnp.uint32),
-               *shape2, jnp.uint32)
+    bf16 = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
+    operands = [bf16(w), bf16(m), bf16(v), bf16(g),
+                bf16(c) if kahan else jnp.zeros_like(w, jnp.bfloat16),
+                (bits if bits is not None
+                 else jnp.zeros(w.shape, jnp.uint32)).astype(jnp.uint32)]
     scalars = jnp.array([[lr, b1, b2, eps, wd, 1.0 - c1, 1.0 - c2]], jnp.float32)
-    grid = (grid_rows // block_rows,)
-    bs = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    out_sds = jax.ShapeDtypeStruct(shape2, jnp.bfloat16)
-    w2, m2, v2, c2_ = pl.pallas_call(
+    w2, m2, v2, c2_ = update_call(
         partial(fused_adamw_kernel, stochastic=stochastic, kahan=kahan),
-        grid=grid,
-        in_specs=[bs, bs, bs, bs, bs, bs,
-                  pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[bs, bs, bs, bs],
-        out_shape=[out_sds, out_sds, out_sds, out_sds],
-        interpret=interpret,
-        name=scopes.FUSED_ADAMW,
-    )(wp, mp, vp, gp, cp, bp, scalars)
-
-    @jax.named_scope(scopes.LAYOUT)
-    def unpad(a):
-        return a.reshape(-1)[:n].reshape(w.shape)
-    return unpad(w2), unpad(m2), unpad(v2), (unpad(c2_) if kahan else None)
+        operands, scalars, (0, 1, 2, 4), name=scopes.FUSED_ADAMW,
+        interpret=interpret, block_rows=block_rows)
+    return w2, m2, v2, (c2_ if kahan else None)

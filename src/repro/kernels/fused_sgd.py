@@ -1,6 +1,9 @@
 """Pallas TPU kernel: fused SGD-momentum update with SR / Kahan rounding.
 
-Same single-HBM-pass rationale as fused_adamw (paper Algorithms 2–3).
+Same single-HBM-pass rationale as fused_adamw (paper Algorithms 2–3), and
+the same layout: ``fused_adamw.tiling`` runs the kernel where a leaf lies
+when its rank is ≥ 2 and its last dim a multiple of 128, and copies any
+other leaf into ``(rows, 128)`` tiles and back (scope ``layout``).
 """
 from __future__ import annotations
 
@@ -8,10 +11,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fused_adamw import _pad2, _sr_to_bf16, BLOCK_ROWS, LANE
+from repro.kernels.fused_adamw import BLOCK_ROWS, _sr_to_bf16, update_call
 from repro.profile import scopes
 
 __all__ = ["fused_sgd", "fused_sgd_kernel"]
@@ -49,34 +50,18 @@ def fused_sgd_kernel(w_ref, m_ref, g_ref, c_ref, bits_ref, scalars_ref,
 def fused_sgd(w, m, g, *, c=None, bits=None, lr, momentum=0.9, wd=0.0,
               stochastic: bool = True, interpret: bool | None = None,
               block_rows: int = BLOCK_ROWS):
-    """One fused SGD step. Returns (w', m', c')."""
+    """One fused SGD step on one leaf. Returns (w', m', c')."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     kahan = c is not None
-    n = w.size
-    rows = max(1, -(-n // LANE))
-    grid_rows = -(-rows // block_rows) * block_rows
-    shape2 = (grid_rows, LANE)
-    wp = _pad2(w, *shape2, jnp.bfloat16)
-    mp = _pad2(m, *shape2, jnp.bfloat16)
-    gp = _pad2(g, *shape2, jnp.bfloat16)
-    cp = _pad2(c if kahan else jnp.zeros_like(w), *shape2, jnp.bfloat16)
-    bp = _pad2(bits if bits is not None else jnp.zeros(w.shape, jnp.uint32),
-               *shape2, jnp.uint32)
+    bf16 = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
+    operands = [bf16(w), bf16(m), bf16(g),
+                bf16(c) if kahan else jnp.zeros_like(w, jnp.bfloat16),
+                (bits if bits is not None
+                 else jnp.zeros(w.shape, jnp.uint32)).astype(jnp.uint32)]
     scalars = jnp.array([[lr, momentum, wd]], jnp.float32)
-    bs = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    out_sds = jax.ShapeDtypeStruct(shape2, jnp.bfloat16)
-    w2, m2, c2 = pl.pallas_call(
+    w2, m2, c2 = update_call(
         partial(fused_sgd_kernel, stochastic=stochastic, kahan=kahan),
-        grid=(grid_rows // block_rows,),
-        in_specs=[bs, bs, bs, bs, bs, pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[bs, bs, bs],
-        out_shape=[out_sds, out_sds, out_sds],
-        interpret=interpret,
-        name=scopes.FUSED_SGD,
-    )(wp, mp, gp, cp, bp, scalars)
-
-    @jax.named_scope(scopes.LAYOUT)
-    def unpad(a):
-        return a.reshape(-1)[:n].reshape(w.shape)
-    return unpad(w2), unpad(m2), (unpad(c2) if kahan else None)
+        operands, scalars, (0, 1, 3), name=scopes.FUSED_SGD,
+        interpret=interpret, block_rows=block_rows)
+    return w2, m2, (c2 if kahan else None)

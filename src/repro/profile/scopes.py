@@ -30,7 +30,7 @@ LOSS = "loss"
 
 # inside OPTIMIZER
 SR_BITS = "sr_bits"            # random bits for stochastic rounding
-LAYOUT = "layout"              # a leaf's relayout into the kernel's tiles and back
+LAYOUT = "layout"              # a flat-path leaf's copy into tiles and back
 FUSED_ADAMW = "fused_adamw"    # the Pallas kernels' own names
 FUSED_SGD = "fused_sgd"
 

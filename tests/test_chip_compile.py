@@ -57,6 +57,17 @@ def test_fused_adamw_kahan_sr(topo):
         *[(LEAF, jnp.bfloat16)] * 5, (LEAF, jnp.uint32))
 
 
+@pytest.mark.parametrize("shape", [(36, 2048, 256), (2048,)],
+                         ids=["native_stacked", "flat"])
+def test_fused_adamw_layouts(topo, shape):
+    """A stacked leaf (36 layers' k projections) runs where it lies, its
+    leading dim squeezed; a norm scale takes the flat (rows, 128) path."""
+    _compile(lambda w, m, v, g, c, bits: fused_adamw(
+        w, m, v, g, c=c, bits=bits, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8,
+        wd=0.01, c1=0.1, c2=0.001, interpret=False), topo,
+        *[(shape, jnp.bfloat16)] * 5, (shape, jnp.uint32))
+
+
 def test_fused_sgd_kahan_sr(topo):
     _compile(lambda w, m, g, c, bits: fused_sgd(
         w, m, g, c=c, bits=bits, lr=1e-3, interpret=False), topo,

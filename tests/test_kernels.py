@@ -1,10 +1,12 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret on CPU) ≡ ref.py."""
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.fused_adamw import fused_adamw
+from repro.kernels.fused_adamw import fused_adamw, tiling
 from repro.kernels.fused_sgd import fused_sgd
 from repro.kernels.qmatmul import qmatmul
 from repro.kernels.sr_cast import sr_cast
@@ -94,17 +96,46 @@ def test_qmatmul_k_accumulation_in_f32():
     assert abs(float(out[0, 0]) / expect - 1) < 0.01
 
 
-@pytest.mark.parametrize("n", [5, 512, 4096, 50_000])
-@pytest.mark.parametrize("stochastic,kahan", [(True, False), (False, False),
-                                              (True, True), (False, True)])
-def test_fused_adamw_sweep(n, stochastic, kahan):
-    key = jax.random.PRNGKey(n)
-    w = jax.random.normal(key, (n,), jnp.bfloat16)
-    m = jax.random.normal(jax.random.fold_in(key, 1), (n,), jnp.bfloat16) * 0.1
-    v = jnp.abs(jax.random.normal(jax.random.fold_in(key, 2), (n,), jnp.bfloat16)) * 0.01
-    g = jax.random.normal(jax.random.fold_in(key, 3), (n,), jnp.bfloat16)
-    c = jnp.zeros((n,), jnp.bfloat16) if kahan else None
-    bits = _bits(key, (n,))
+# Flat cases (an int n: a leaf of shape (n,)) are raveled into (rows, 128)
+# tiles; native cases (a shape) run where they lie, each with ragged edge
+# blocks (rows or columns that no block divides). The kernel's
+# arithmetic on random data is held to the reference within 1 ulp on the
+# flat cases; every case is held bit for bit on the dyadic grid, against
+# the reference and against the flat path on the raveled leaf.
+NATIVE = [(3, 40, 1152), (300, 384), (2, 48, 1408)]
+ADAMW_CASES = [5, 512, 4096, 50_000, *NATIVE]
+SGD_CASES = [3, 1000, 8192, *NATIVE]
+DYADIC_ADAMW = dict(lr=2.0 ** -6, b1=0.5, b2=0.5, eps=2.0 ** -10, wd=0.0,
+                    c1=0.5, c2=0.5)
+DYADIC_SGD = dict(lr=0.25, momentum=0.5, wd=0.0)
+
+
+def _shape(n):
+    shape = n if isinstance(n, tuple) else (n,)
+    native, kshape, block, _ = tiling(shape)
+    assert native == isinstance(n, tuple)
+    if native:
+        assert kshape[-2] % block[-2] or kshape[-1] % block[-1], "not ragged"
+    return shape
+
+
+def _assert_bitwise(got, want, flat, shape):
+    """``got`` equals the reference and the flat path's outputs, bit for
+    bit, element for element."""
+    for a, b, f in zip(got, want, flat):
+        if a is None:
+            assert b is None and f is None
+        else:
+            assert bool(jnp.all(a == b))
+            assert bool(jnp.all(a == f.reshape(shape)))
+
+
+def _adamw_close_on_random_data(key, shape, bits, stochastic, kahan):
+    w = jax.random.normal(key, shape, jnp.bfloat16)
+    m = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.bfloat16) * 0.1
+    v = jnp.abs(jax.random.normal(jax.random.fold_in(key, 2), shape, jnp.bfloat16)) * 0.01
+    g = jax.random.normal(jax.random.fold_in(key, 3), shape, jnp.bfloat16)
+    c = jnp.zeros(shape, jnp.bfloat16) if kahan else None
     got = fused_adamw(w, m, v, g, c=c, bits=bits, stochastic=stochastic, **HP)
     want = ref.fused_adamw_ref(w, m, v, g, c=c, bits=bits,
                                stochastic=stochastic, **HP)
@@ -120,16 +151,38 @@ def test_fused_adamw_sweep(n, stochastic, kahan):
             assert_bf16_close(a, b, scale=w if i == 3 else None, atol=atol)
 
 
-@pytest.mark.parametrize("n", [3, 1000, 8192])
-@pytest.mark.parametrize("stochastic,kahan", [(True, False), (True, True),
-                                              (False, True)])
-def test_fused_sgd_sweep(n, stochastic, kahan):
-    key = jax.random.PRNGKey(n + 1)
-    w = jax.random.normal(key, (n,), jnp.bfloat16)
-    m = jnp.zeros((n,), jnp.bfloat16)
-    g = jax.random.normal(jax.random.fold_in(key, 1), (n,), jnp.bfloat16)
-    c = jnp.zeros((n,), jnp.bfloat16) if kahan else None
-    bits = _bits(key, (n,))
+@pytest.mark.parametrize("n", ADAMW_CASES)
+@pytest.mark.parametrize("stochastic,kahan", [(True, False), (False, False),
+                                              (True, True), (False, True)])
+def test_fused_adamw_sweep(n, stochastic, kahan):
+    shape = _shape(n)
+    key = jax.random.PRNGKey(math.prod(shape))
+    bits = _bits(key, shape)
+    if isinstance(n, int):
+        _adamw_close_on_random_data(key, shape, bits, stochastic, kahan)
+    # on the dyadic grid the arithmetic is exact, so the layout may change
+    # no bit: the kernel equals the reference and the flat path
+    ks = jax.random.split(jax.random.fold_in(key, 4), 5)
+    w, g = _dyadic(ks[0], shape), _dyadic(ks[1], shape)
+    m = _dyadic(ks[2], shape, scale=0.25)
+    v = jnp.abs(_dyadic(ks[3], shape, scale=0.25))
+    c = _dyadic(ks[4], shape, scale=2.0 ** -8) if kahan else None
+    flat_in = [x.reshape(-1) for x in (w, m, v, g)]
+    got = fused_adamw(w, m, v, g, c=c, bits=bits, stochastic=stochastic,
+                      **DYADIC_ADAMW)
+    want = ref.fused_adamw_ref(w, m, v, g, c=c, bits=bits,
+                               stochastic=stochastic, **DYADIC_ADAMW)
+    flat = fused_adamw(*flat_in, c=None if c is None else c.reshape(-1),
+                       bits=bits.reshape(-1), stochastic=stochastic,
+                       **DYADIC_ADAMW)
+    _assert_bitwise(got, want, flat, shape)
+
+
+def _sgd_close_on_random_data(key, shape, bits, stochastic, kahan):
+    w = jax.random.normal(key, shape, jnp.bfloat16)
+    m = jnp.zeros(shape, jnp.bfloat16)
+    g = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.bfloat16)
+    c = jnp.zeros(shape, jnp.bfloat16) if kahan else None
     got = fused_sgd(w, m, g, c=c, bits=bits, stochastic=stochastic,
                     lr=0.1, momentum=0.9, wd=1e-4)
     want = ref.fused_sgd_ref(w, m, g, c=c, bits=bits, stochastic=stochastic,
@@ -142,6 +195,30 @@ def test_fused_sgd_sweep(n, stochastic, kahan):
                                   + jnp.abs(g.astype(jnp.float32)))
                     if i == 1 else None)
             assert_bf16_close(a, b, scale=w if i == 2 else None, atol=atol)
+
+
+@pytest.mark.parametrize("n", SGD_CASES)
+@pytest.mark.parametrize("stochastic,kahan", [(True, False), (True, True),
+                                              (False, True), (False, False)])
+def test_fused_sgd_sweep(n, stochastic, kahan):
+    shape = _shape(n)
+    key = jax.random.PRNGKey(math.prod(shape) + 1)
+    bits = _bits(key, shape)
+    if isinstance(n, int):
+        _sgd_close_on_random_data(key, shape, bits, stochastic, kahan)
+    ks = jax.random.split(jax.random.fold_in(key, 2), 4)
+    w, g = _dyadic(ks[0], shape), _dyadic(ks[1], shape)
+    m = _dyadic(ks[2], shape, scale=0.25)
+    c = _dyadic(ks[3], shape, scale=2.0 ** -8) if kahan else None
+    got = fused_sgd(w, m, g, c=c, bits=bits, stochastic=stochastic,
+                    **DYADIC_SGD)
+    want = ref.fused_sgd_ref(w, m, g, c=c, bits=bits, stochastic=stochastic,
+                             **DYADIC_SGD)
+    flat = fused_sgd(w.reshape(-1), m.reshape(-1), g.reshape(-1),
+                     c=None if c is None else c.reshape(-1),
+                     bits=bits.reshape(-1), stochastic=stochastic,
+                     **DYADIC_SGD)
+    _assert_bitwise(got, want, flat, shape)
 
 
 def test_fused_kahan_accumulates_small_updates():

@@ -106,16 +106,22 @@ class TestShardLocal:
     def _setup(self, pol):
         from jax.sharding import NamedSharding, PartitionSpec as P
         policy = get_policy(pol)
+        # "w" and "b" take the kernel's flat path on their local shards,
+        # "u" its native one: the shard (8, 256) keeps a 128-multiple last dim
         params = {"w": jax.random.normal(jax.random.PRNGKey(0), (64, 32),
                                          jnp.bfloat16),
                   "b": jax.random.normal(jax.random.PRNGKey(1), (32,),
+                                         jnp.bfloat16),
+                  "u": jax.random.normal(jax.random.PRNGKey(6), (64, 256),
                                          jnp.bfloat16)}
         grads = {"w": jax.random.normal(jax.random.PRNGKey(2), (64, 32),
                                         jnp.bfloat16),
                  "b": jax.random.normal(jax.random.PRNGKey(3), (32,),
+                                        jnp.bfloat16),
+                 "u": jax.random.normal(jax.random.PRNGKey(7), (64, 256),
                                         jnp.bfloat16)}
         mesh = make_mesh((8,), ("fsdp",))
-        pspecs = {"w": P("fsdp", None), "b": P("fsdp")}
+        pspecs = {"w": P("fsdp", None), "b": P("fsdp"), "u": P("fsdp", None)}
         sharded = {k: jax.device_put(v, NamedSharding(mesh, pspecs[k]))
                    for k, v in params.items()}
         gsharded = {k: jax.device_put(v, NamedSharding(mesh, pspecs[k]))

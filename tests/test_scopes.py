@@ -9,6 +9,7 @@ import pytest
 
 from repro.core import get_policy
 from repro.data.synthetic import lm_batches
+from repro.kernels.fused_adamw import tiling
 from repro.models import registry as R
 from repro.optim import constant
 from repro.optim.fused import fused_adamw_optimizer
@@ -55,9 +56,19 @@ def test_step_and_optimizer_scopes_in_hlo(hlo):
     found = {s for n in names if n.startswith("jit(train_step)")
              for s in _scopes(n)}
     # (one device, no wire: the transport adds no op and so no name)
-    for want in (S.MODEL, S.OPTIMIZER, S.SR_BITS, S.LAYOUT, S.STEP_METRICS,
+    for want in (S.MODEL, S.OPTIMIZER, S.SR_BITS, S.STEP_METRICS,
                  S.ATTENTION, S.MLP, S.NORM, S.EMBED, S.HEAD, S.LOSS):
         assert want in found, want
+    # the reduced model has leaves of both layouts (e.g. the final norm's
+    # (128,) is flat, the embedding's (512, 128) native); only the flat
+    # ones are copied into tiles and back under optimizer/layout, each
+    # slicing its four outputs (w, m, v, c) out of the tiles
+    params = R.init(CFG, jax.random.PRNGKey(0), POLICY.param_dtype)
+    native = [tiling(x.shape)[0] for x in jax.tree_util.tree_leaves(params)]
+    assert any(native) and not all(native)
+    slices = [n for n in names
+              if n.endswith(f"/{S.OPTIMIZER}/{S.LAYOUT}/slice")]
+    assert len(slices) == 4 * native.count(False)
     # interpret mode lowers the kernel to a loop that keeps its name
     assert any(f"/{S.OPTIMIZER}/{S.FUSED_ADAMW}/" in n for n in names)
 
@@ -107,11 +118,16 @@ def test_scope_names_are_distinct():
 
 
 def test_fused_sgd_kernel_named():
+    """A leaf on the native path lowers with no op under ``layout``; a
+    leaf on the flat path is copied into tiles and back there."""
     from repro.kernels.fused_sgd import fused_sgd
-    w = jnp.ones((4, 64), jnp.bfloat16)
-    text = jax.jit(lambda w, m, g: fused_sgd(w, m, g, lr=1e-3)).lower(
-        w, w, w).as_text(debug_info=True)
-    assert S.FUSED_SGD in text and S.LAYOUT in text
+    for shape, flat in (((16, 256), False), ((4, 64), True)):
+        w = jnp.ones(shape, jnp.bfloat16)
+        text = jax.jit(lambda w, m, g: fused_sgd(w, m, g, lr=1e-3)).lower(
+            w, w, w).as_text(debug_info=True)
+        assert S.FUSED_SGD in text
+        assert (S.LAYOUT in text) == flat, shape
+        assert tiling(shape)[0] != flat
 
 
 def test_gradient_accumulation_under_transport():
