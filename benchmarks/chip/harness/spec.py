@@ -3,7 +3,8 @@
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric sits in a file of its own, named in ``BENCHMARK.json``:
 
-* ``configs/<config>.json``  -- the sizes as run (Hugging Face keys);
+* ``configs/<config>.json``  -- the sizes as run (Hugging Face keys),
+  whose ``reference`` key names the architecture (``harness.arch``);
 * ``traffic/<traffic>.json`` -- the mix's parameters, read by the one
   harness of its ``kind`` (``train``);
 * ``limits/<workload>.json`` -- the limits that decide ``correct``;
@@ -18,6 +19,8 @@ import importlib.util
 import json
 from pathlib import Path
 from typing import Any
+
+from harness import arch
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parents[1]
@@ -54,6 +57,7 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
     w = by_name[name]
     conf = next(c for c in bench["configs"] if c["name"] == w["config"])
     config = json.loads((ROOT / conf["file"]).read_text())
+    arch.module(config)         # an unknown architecture stops here
     traffic = json.loads((BENCH_DIR / "traffic" / f"{w['traffic']}.json")
                          .read_text())
     limits_file = BENCH_DIR / "limits" / f"{name}.json"
@@ -77,23 +81,6 @@ def metric_reader(name: str):
 
 
 def model_config(config: dict) -> Any:
-    """The program's ``ModelConfig`` for a dense decoder configuration
-    given in Hugging Face keys."""
-    from repro.configs.base import ModelConfig
-    # what the program's dense decoder fixes; a configuration stating
-    # otherwise would be run as something it is not
-    if config["rms_norm_eps"] != 1e-6 or config["hidden_act"] != "silu":
-        raise ValueError(f"{config['name']}: the program runs RMSNorm eps "
-                         f"1e-6 and SiLU only")
-    d = config["hidden_size"]
-    heads = config["num_attention_heads"]
-    return ModelConfig(
-        name=config["name"], family="lm",
-        n_layers=config["num_hidden_layers"], d_model=d,
-        vocab=config["vocab_size"], n_heads=heads,
-        n_kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim", d // heads),
-        qkv_bias=bool(config["attention_bias"]),
-        rope_theta=float(config["rope_theta"]),
-        d_ff=config["intermediate_size"], norm="rms",
-        tie_embeddings=bool(config["tie_word_embeddings"]))
+    """The program's ``ModelConfig`` for a configuration given in Hugging
+    Face keys, made by its architecture's module (``harness.arch``)."""
+    return arch.module(config).model_config(config)

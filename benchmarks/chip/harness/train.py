@@ -156,7 +156,7 @@ def run(cell: Cell, seed: int, seconds: float, *, devices, t_start: float,
             "setup_s": time.perf_counter() - t_start,
             "memory_peak_bytes": peak_bytes(devices)})
         rec.extra["readings"] = _check(cell, seed, None, control, make,
-                                       shapes, w_key, policy)
+                                       shapes, w_key, policy, devices)
         return rec
     run_ = build_training(cfg, policy, seed=pseed, lr=opt["lr"],
                           steps=opt["schedule_steps"], seq=tr["seq"],
@@ -164,9 +164,9 @@ def run(cell: Cell, seed: int, seconds: float, *, devices, t_start: float,
                           fused_update=tr["fused_update"],
                           grad_accum=tr.get("grad_accum", 1))
     _log("program built")
+    placed = run_.shardings.params if run_.shardings else None
     params = weights.make(shapes, w_key, policy.param_dtype,
-                          out_shardings=(run_.shardings.params
-                                         if run_.shardings else None))
+                          out_shardings=placed)
     # the launcher's own random weights are dropped for the benchmark's
     state = run_.state._replace(params=params)
     run_ = run_._replace(state=None)
@@ -188,7 +188,8 @@ def run(cell: Cell, seed: int, seconds: float, *, devices, t_start: float,
                                      1.0 / (1.0 - opt["b1"]))
         if calls == n_check:
             w0 = jax.tree_util.tree_leaves(
-                weights.make(shapes, w_key, policy.param_dtype))
+                weights.make(shapes, w_key, policy.param_dtype,
+                             out_shardings=placed))
             ws = jax.tree_util.tree_leaves(state.params)
             cs = (jax.tree_util.tree_leaves(state.opt_state.kahan_c)
                   if policy.kahan else [jnp.zeros((), w.dtype) for w in ws])
@@ -225,7 +226,7 @@ def run(cell: Cell, seed: int, seconds: float, *, devices, t_start: float,
     del state, step, run_
     _log("window done")
     rec.extra["readings"] = _check(cell, seed, readings_prog, None, make,
-                                   shapes, w_key, policy)
+                                   shapes, w_key, policy, devices)
     return rec
 
 
@@ -255,37 +256,40 @@ def _log(msg: str) -> None:
           flush=True)
 
 
-def _check(cell, seed, prog, control, make, shapes, w_key, policy) -> dict:
+def _check(cell, seed, prog, control, make, shapes, w_key, policy,
+           devices) -> dict:
     """Readings of the program (``prog``; None where ``control`` takes its
     place) or of ``control`` (the fp8 control or the half-batch fault)
-    against the float32 reference over the same first steps. The control
-    runs first and keeps its first gradient, so the reference compares
-    it with its own."""
+    against the float32 reference over the same first steps, on the
+    cell's ``devices``. The control runs first and keeps its first
+    gradient, so the reference compares it with its own."""
     from reference import train as ref_train
     _release_host_memory()
     _log("check starts")
     tr = cell.traffic
     n = tr["check_steps"]
     batches = [make(i) for i in range(n)]
-    w0 = weights.make(shapes, w_key, policy.param_dtype)
+    w0 = weights.make(shapes, w_key, policy.param_dtype,
+                      out_shardings=ref_train.shardings(shapes, devices))
     runs = {} if prog is None else {"program": prog}
     if control == "fp8":
         runs["control"] = ref_train.run(cell.config, w0, batches,
-                                        tr["optimizer"], steps=n, mm="fp8",
+                                        tr["optimizer"], steps=n,
+                                        devices=devices, mm="fp8",
                                         keep_grad=True, log=_log)
     elif control == "half_batch":
         # a fault planted in the reference put in the program's place:
         # the mean taken over the first half of each row's tokens only
         runs["fault_half_batch"] = ref_train.run(
             cell.config, w0, batches, tr["optimizer"], steps=n,
-            half_batch=True, keep_grad=True, log=_log)
+            devices=devices, half_batch=True, keep_grad=True, log=_log)
     elif control is not None:
         raise ValueError(f"unknown control {control!r}")
     _release_host_memory()
     grads = {k: r.pop("grad") for k, r in runs.items()}
     grads = {k: g if k == "program" else (g, 1.0) for k, g in grads.items()}
     ref = ref_train.run(cell.config, w0, batches, tr["optimizer"], steps=n,
-                        compare=grads, log=_log)
+                        devices=devices, compare=grads, log=_log)
     del grads
     return {k: check.train_readings(r, ref, ref["grad_diff"][k])
             for k, r in runs.items()}

@@ -10,7 +10,9 @@ from the persistent cache, warm-up) is timed as ``setup_s``; then the
 window runs for ``--seconds``; then the check runs against the plain
 float32 reference. ``--trace 1`` traces the window with the JAX
 profiler and reports the per-layer metrics instead of the end-to-end
-ones. The last line of standard output is one JSON object.
+ones, and under ``scopes`` the step's largest parts by direction and
+scope path, in ms per step. The last line of standard output is one
+JSON object.
 
 Every run prints, under ``readings``, each number the check read, also
 those without a limit. ``--control fp8`` or ``--control half_batch``
@@ -89,6 +91,8 @@ def measure(cell, seed: int, seconds: float, *, trace: bool, devices,
         dev["busy_s"] = rec.trace.busy_s
         dev["window_s"] = rec.trace.window_s
         out["breakdown"] = rec.trace.breakdown()
+    if rec.extra.get("scopes") is not None:
+        out["scopes"] = rec.extra["scopes"].top(rec.steps)
     # every number read, compared or not (the control's with --control)
     out["readings"] = dict(readings)
     out["checks"] = shown

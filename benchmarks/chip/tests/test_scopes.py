@@ -327,3 +327,32 @@ def test_reader_refuses_another_runs_trace(tmp_path, monkeypatch):
     rec.trace = dc.replace(rec.trace, window_s=rec.trace.window_s + 1.0)
     with pytest.raises(ValueError, match="is not the run's"):
         spec.metric_reader("optimizer_ms.train")(rec)
+
+
+@pytest.mark.parametrize("trace_file, steps", [(NEW, 3), (OLD, 25)],
+                         ids=["scoped", "unscoped"])
+def test_measure_puts_the_scopes_in_the_last_line(trace_file, steps,
+                                                  tmp_path, monkeypatch):
+    """A traced run's result line carries the step's largest scopes, in ms
+    per step (none for a program that names no scope), and still ends
+    with the compared numbers."""
+    import types
+
+    import run
+    from harness import train
+    rec = _record(trace_file, tmp_path, monkeypatch, steps=steps)
+    rec.extra.update(readings={"program": {}}, memory_peak_bytes=1)
+    monkeypatch.setattr(train, "run", lambda *a, **kw: rec)
+    monkeypatch.setattr(run, "TRACE_DIR", tmp_path / "out" / "trace")
+    chip = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    out = run.measure(rec.cell, 1, 1.0, trace=True, devices=[chip],
+                      t_start=0.0)
+    if trace_file == NEW:
+        want = SC.reduce(SC.read_xspace(str(NEW))).top(steps)
+        assert out["scopes"] == want and len(want) == SC.TOP
+        assert out["scopes"][0][0] == "outside/optimizer/layout"
+        assert "optimizer_ms.train" in out["metrics"]
+    else:
+        assert "scopes" not in out
+        assert "optimizer_ms.train" not in out["metrics"]
+    assert list(out)[-1] == "checks"

@@ -26,19 +26,21 @@ def _metrics(*names):
     return [{"name": n, "unit": "-"} for n in names]
 
 
-def cell(traffic_name: str, limits: dict, e2e: list, **overrides):
+def cell(traffic_name: str, limits: dict, e2e: list, chips: int = 1,
+         **overrides):
     traffic = dict(_json("traffic", f"{traffic_name}.json"), **overrides)
-    return spec.Cell(f"toy.{traffic_name}", 1, "tiny", dict(TINY),
+    return spec.Cell(f"toy.{traffic_name}", chips, "tiny", dict(TINY),
                      traffic_name, traffic, limits, e2e, [])
 
 
-def train_cell(**kw):
+def train_cell(seq=64, **kw):
     return cell("train.4k", _json("limits", "yi-9b.train.4k.json"),
-                _metrics("train_tokens_per_s", "setup_s"), seq=64, **kw)
+                _metrics("train_tokens_per_s", "setup_s"), seq=seq, **kw)
 
 
 def measure(c, seed=12345678901, seconds=2.0, **kw):
+    """A whole run of the cell ``c`` on its first ``c.chips`` devices."""
     import run
     return run.measure(c, seed, seconds, trace=False,
-                       devices=jax.devices()[:1],
+                       devices=jax.devices()[:c.chips],
                        t_start=time.perf_counter(), **kw)
