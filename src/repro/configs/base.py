@@ -25,18 +25,25 @@ class ModelConfig:
     n_kv_heads: int = 0
     head_dim: int = 128
     qkv_bias: bool = False
+    qk_norm: bool = False                 # RMSNorm over head_dim on q and k
     swa_window: Optional[int] = None      # sliding-window size (None = full)
     rope_theta: float = 10000.0
     rope_type: str = "std"                # std | mrope
     mrope_sections: tuple[int, ...] = ()  # head_dim/2 split for t/h/w
     # mlp / moe
     d_ff: int = 0
-    n_experts: int = 0                    # 0 = dense
+    n_experts: int = 0                    # 0 = dense; the router's width
     top_k: int = 1
     shared_expert: bool = False
     capacity_factor: float = 1.25
-    moe_strategy: str = "onehot"          # onehot | grouped | gather (§Perf)
+    moe_strategy: str = "onehot"          # onehot | grouped | gather | dropless
     moe_group_size: int = 1024            # routing-group tokens (grouped)
+    # dropless only: this layer holds experts [expert_offset,
+    # expert_offset + experts_held) of the router's n_experts (0 → all)
+    experts_held: int = 0
+    expert_offset: int = 0
+    norm_topk_prob: bool = False          # renormalise the top-k gates
+    router_aux_coef: float = 0.0          # load-balancing loss coefficient
     # norm / embeddings
     norm: str = "rms"                     # rms | ln
     tie_embeddings: bool = True
@@ -68,6 +75,11 @@ class ModelConfig:
     def sub_quadratic(self) -> bool:
         return (self.attention_free or bool(self.block_pattern)
                 or self.swa_window is not None)
+
+    @property
+    def n_held(self) -> int:
+        """Experts this layer holds (dropless); all of them otherwise."""
+        return self.experts_held or self.n_experts
 
     @property
     def d_inner(self) -> int:

@@ -76,6 +76,16 @@ class QArith:
         out = jnp.einsum(spec, *args, preferred_element_type=jnp.float32)
         return self.cast(out)
 
+    def grouped_matmul(self, x: jax.Array, w: jax.Array, group_sizes,
+                       group_offset) -> jax.Array:
+        """Rows of ``x`` sorted by group, each held group's rows through
+        its matrix of ``w`` (:func:`repro.kernels.grouped_matmul.gmm`);
+        the rows of groups not held come back 0. Rounded as ``einsum``."""
+        from repro.kernels.grouped_matmul import gmm
+        out_dtype = self.dtype if self._native else jnp.float32
+        return self.cast(gmm(self._fmac_in(x), self._fmac_in(w), group_sizes,
+                             group_offset, out_dtype=out_dtype))
+
     def matmul_f32out(self, a: jax.Array, b: jax.Array) -> jax.Array:
         """Contraction leaving the result in the 32-bit accumulator — used
         when the very next op consumes it fused (e.g. logits → softmax-CE)."""

@@ -386,12 +386,16 @@ def decode_attention(qa: QArith, q, k_cache, v_cache, k_pos, *, q_pos,
 def attention_init(key, cfg, dtype=jnp.float32):
     ks = jax.random.split(key, 4)
     hd = cfg.head_dim
-    return {
+    p = {
         "wq": dense_init(ks[0], cfg.d_model, cfg.n_heads * hd, bias=cfg.qkv_bias, dtype=dtype),
         "wk": dense_init(ks[1], cfg.d_model, cfg.n_kv_heads * hd, bias=cfg.qkv_bias, dtype=dtype),
         "wv": dense_init(ks[2], cfg.d_model, cfg.n_kv_heads * hd, bias=cfg.qkv_bias, dtype=dtype),
         "wo": dense_init(ks[3], cfg.n_heads * hd, cfg.d_model, dtype=dtype),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = norm_init("rms", hd, dtype)
+        p["k_norm"] = norm_init("rms", hd, dtype)
+    return p
 
 
 def attention_apply(qa: QArith, p, x, cfg, *, positions, causal=True,
@@ -424,8 +428,12 @@ def attention_apply(qa: QArith, p, x, cfg, *, positions, causal=True,
     B, S, _ = x.shape
     hd = cfg.head_dim
     q = dense(qa, p["wq"], x).reshape(B, S, cfg.n_heads, hd)
+    if cfg.qk_norm:     # RMSNorm over head_dim, before RoPE
+        q = norm_apply(qa, "rms", p["q_norm"], q)
     if kv_override is None:
         k = dense(qa, p["wk"], x).reshape(B, S, cfg.n_kv_heads, hd)
+        if cfg.qk_norm:
+            k = norm_apply(qa, "rms", p["k_norm"], k)
         v = dense(qa, p["wv"], x).reshape(B, S, cfg.n_kv_heads, hd)
         if cfg.rope_type == "mrope" and mrope_positions is not None:
             q = mrope(q, mrope_positions, cfg.mrope_sections, cfg.rope_theta)

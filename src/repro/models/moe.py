@@ -1,6 +1,7 @@
-"""Mixture-of-Experts feed-forward with capacity-based dispatch.
+"""Mixture-of-Experts feed-forward: capacity-based dispatch, or dropless
+over the share of the experts a layer holds.
 
-Two dispatch strategies (selectable; the §Perf hillclimb compares them):
+Capacity strategies (selectable; the §Perf hillclimb compares them):
 
 * ``onehot`` — GShard/Switch-style dispatch/combine einsums against a
   (tokens, experts, capacity) one-hot tensor. Simple, SPMD-friendly
@@ -12,18 +13,30 @@ Two dispatch strategies (selectable; the §Perf hillclimb compares them):
 Routing is computed in f32 (router logits are numerically delicate — this
 matches production MoE stacks and the paper's fused-op convention).
 Over-capacity tokens are dropped (their expert contribution is zero), the
-standard trade-off at scale.
+standard trade-off at scale. These paths hold every expert.
+
+* ``dropless`` — the layer holds experts ``[expert_offset, expert_offset
+  + experts_held)`` of the router's ``n_experts`` (one chip's share under
+  expert parallelism). The router keeps its full width; every (token,
+  expert) assignment to a held expert is computed, none is dropped, by
+  grouped matmuls whose work scales with the rows routed here
+  (:meth:`QArith.grouped_matmul`). Assignments to experts held elsewhere
+  add nothing here. The router weight is a leaf in the param dtype, like
+  every other; its logits stay in the f32 accumulator into the softmax.
 """
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.qarith import QArith
+from repro.profile import scopes
 
-__all__ = ["moe_init", "moe_apply", "mlp_init", "mlp_apply"]
+__all__ = ["moe_init", "moe_apply", "dropless_apply", "mlp_init",
+           "mlp_apply"]
 
 
 def mlp_init(key, d_model: int, d_ff: int, dtype=jnp.float32):
@@ -47,12 +60,18 @@ def mlp_apply(qa: QArith, p, x, act: str = "silu"):
 def moe_init(key, cfg, dtype=jnp.float32):
     ks = jax.random.split(key, 5)
     E, D, F = cfg.n_experts, cfg.d_model, cfg.d_ff
+    dropless = cfg.moe_strategy == "dropless"
+    if cfg.n_held != E and not dropless:
+        raise ValueError("only moe_strategy='dropless' holds a share of "
+                         "the experts")
+    H = cfg.n_held
     s_in, s_ff = 1 / math.sqrt(D), 1 / math.sqrt(F)
+    router_dtype = dtype if dropless else jnp.float32
     p = {
-        "router": (jax.random.normal(ks[0], (D, E)) * s_in).astype(jnp.float32),
-        "we_gate": (jax.random.normal(ks[1], (E, D, F)) * s_in).astype(dtype),
-        "we_up": (jax.random.normal(ks[2], (E, D, F)) * s_in).astype(dtype),
-        "we_down": (jax.random.normal(ks[3], (E, F, D)) * s_ff).astype(dtype),
+        "router": (jax.random.normal(ks[0], (D, E)) * s_in).astype(router_dtype),
+        "we_gate": (jax.random.normal(ks[1], (H, D, F)) * s_in).astype(dtype),
+        "we_up": (jax.random.normal(ks[2], (H, D, F)) * s_in).astype(dtype),
+        "we_down": (jax.random.normal(ks[3], (H, F, D)) * s_ff).astype(dtype),
     }
     if cfg.shared_expert:
         p["shared"] = mlp_init(ks[4], D, F, dtype)
@@ -167,10 +186,86 @@ def _moe_gather(qa, p, x, cfg, capacity):
     return y.reshape(B, S, D)
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, idx, inv, fold: int):
+    """``x[idx]``, where ``idx`` takes each row of ``x`` ``fold`` times
+    and ``inv[i·fold : (i+1)·fold]`` says where row i's copies went. The
+    gradient is gathered back through ``inv`` and summed over the copies
+    in f32, rounded once: no scatter."""
+    return jnp.take(x, idx, axis=0)
+
+
+def _take_rows_fwd(x, idx, inv, fold):
+    return _take_rows(x, idx, inv, fold), inv
+
+
+def _take_rows_bwd(fold, inv, g):
+    d = jnp.take(g, inv, axis=0).astype(jnp.float32)
+    d = d.reshape(-1, fold, g.shape[-1]).sum(axis=1).astype(g.dtype)
+    return d, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def dropless_apply(qa: QArith, p, x, cfg):
+    """x: (B,S,D) → ((B,S,D), stats). The layer holds experts
+    ``cfg.expert_offset ..`` (``cfg.n_held`` of them) of the router's
+    ``cfg.n_experts``. Softmax over all of them, top-k, renormalised over
+    the k chosen when ``cfg.norm_topk_prob``; the T·k assignments sorted
+    by expert, the held experts' rows through SwiGLU by grouped matmuls,
+    unsorted and summed per token with their gates (in f32, rounded
+    once). No token is dropped: the sorted buffer holds all T·k rows.
+
+    ``stats`` (f32 scalars): ``router_loss``, the Switch load-balancing
+    loss over all the router's experts, E·Σ_e (assignments to e / T)·
+    (mean probability of e) (k at balance); ``held_share``, the share of
+    the assignments held here; ``max_load``, the busiest held expert's
+    assignments over the held experts' mean."""
+    B, S, D = x.shape
+    T = B * S
+    E, k, H = cfg.n_experts, cfg.top_k, cfg.n_held
+    first = cfg.expert_offset
+    xt = x.reshape(T, D)
+    with jax.named_scope(scopes.ROUTER):
+        probs = jax.nn.softmax(qa.matmul_f32out(xt, p["router"]), axis=-1)
+        gate, expert = jax.lax.top_k(probs, k)             # (T,k)
+        if cfg.norm_topk_prob:
+            gate = gate / gate.sum(axis=-1, keepdims=True)
+        sizes = jnp.bincount(expert.reshape(-1), length=E).astype(jnp.int32)
+        router_loss = E * jnp.sum(sizes.astype(jnp.float32) / T
+                                  * probs.mean(axis=0))
+        held = jax.lax.dynamic_slice_in_dim(sizes, first, H)
+    with jax.named_scope(scopes.DISPATCH):
+        # assignment j = t·k + i sits at row slot[j] of the sorted buffer,
+        # whose row s holds assignment order[s]: by expert, then token
+        order = jnp.argsort(expert.reshape(-1), stable=True)
+        slot = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=order.dtype))
+        xs = _take_rows(xt, order // k, slot, k)           # (T·k, D)
+    with jax.named_scope(scopes.EXPERTS):
+        g = qa.grouped_matmul(xs, p["we_gate"], sizes, first)
+        u = qa.grouped_matmul(xs, p["we_up"], sizes, first)
+        h = qa.mul(qa.silu(g) if cfg.act_fn == "silu" else qa.gelu(g), u)
+        # rows of experts held elsewhere come back 0 from the kernel
+        ys = qa.grouped_matmul(h, p["we_down"], sizes, first)
+    with jax.named_scope(scopes.COMBINE):
+        yt = _take_rows(ys, slot, order, 1).reshape(T, k, D)
+        y = qa.cast((yt.astype(jnp.float32) * gate[..., None]).sum(axis=1))
+    y = y.reshape(B, S, D)
+    if cfg.shared_expert:
+        y = qa.add(y, mlp_apply(qa, p["shared"], x, cfg.act_fn))
+    n_held = held.sum().astype(jnp.float32)
+    stats = {"router_loss": router_loss, "held_share": n_held / (T * k),
+             "max_load": held.max() * H / jnp.maximum(n_held, 1.0)}
+    return y, stats
+
+
 def moe_apply(qa: QArith, p, x, cfg, *, strategy: str | None = None):
     """x: (B,S,D) → (B,S,D). Strategy (see module docstring):
     ``onehot`` (global baseline) | ``grouped`` (production GShard) |
-    ``gather`` (index-based, no dispatch FLOPs)."""
+    ``gather`` (index-based, no dispatch FLOPs). A ``dropless`` layer
+    runs :func:`dropless_apply`."""
     B, S, D = x.shape
     T = B * S
     E, k = cfg.n_experts, cfg.top_k

@@ -63,17 +63,23 @@ def init(cfg, key, dtype=jnp.float32):
 
 
 def forward_logits(qa: QArith, params, cfg, batch: dict[str, Any], *,
-                   remat: bool = True, attn_chunk: int = 1024):
-    """Teacher-forced logits (B,S,V) f32 for any family."""
+                   remat: bool = True, attn_chunk: int = 1024,
+                   with_stats: bool = False):
+    """Teacher-forced logits (B,S,V) f32 for any family; with
+    ``with_stats``, (logits, the expert layers' router loss and load
+    counters), which are empty for a model without such layers."""
     if cfg.encdec:
         enc_out = ED.encode(qa, params, cfg, batch["src_embeds"],
                             remat=remat, attn_chunk=attn_chunk)
-        return ED.decoder_forward(qa, params, cfg, batch["tokens"], enc_out,
-                                  remat=remat, attn_chunk=attn_chunk)
+        logits = ED.decoder_forward(qa, params, cfg, batch["tokens"],
+                                    enc_out, remat=remat,
+                                    attn_chunk=attn_chunk)
+        return (logits, {}) if with_stats else logits
     tokens = batch.get("tokens", batch.get("embeds"))
     return T.forward(qa, params, cfg, tokens,
                      mrope_positions=batch.get("mrope_positions"),
-                     remat=remat, attn_chunk=attn_chunk)
+                     remat=remat, attn_chunk=attn_chunk,
+                     with_stats=with_stats)
 
 
 def make_cache(qa: QArith, params, cfg, batch: dict[str, Any], *,

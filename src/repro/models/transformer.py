@@ -61,7 +61,10 @@ def block_init(key, cfg, kind: str, dtype=jnp.float32) -> PyTree:
 def block_apply(qa: QArith, cfg, kind: str, p, x, *, positions,
                 cache=None, cache_pos=None, mrope_positions=None,
                 attn_chunk: int = 1024, block_table=None):
-    """Returns (x, new_cache). cache=None for full-sequence (train/prefill)."""
+    """Returns (x, new_cache, stats). cache=None for full-sequence
+    (train/prefill). ``stats`` holds a dropless expert layer's router
+    loss and load counters (:func:`repro.models.moe.dropless_apply`),
+    else nothing."""
     with jax.named_scope(scopes.NORM):
         h = L.norm_apply(qa, cfg.norm, p["ln1"], x)
     new_cache = None
@@ -74,7 +77,7 @@ def block_apply(qa: QArith, cfg, kind: str, p, x, *, positions,
                                  "step; chunked prefill requires an "
                                  "attention-only block pattern")
             y, new_cache = S.mamba_decode_step(qa, p["mixer"], h, cfg, cache)
-        return qa.add(x, y), new_cache
+        return qa.add(x, y), new_cache, {}
     if kind == "rec":
         if cache is None:
             y = R.rglru_apply(qa, p["mixer"], h, cfg)
@@ -96,12 +99,15 @@ def block_apply(qa: QArith, cfg, kind: str, p, x, *, positions,
     x = qa.add(x, y)
     with jax.named_scope(scopes.NORM):
         h = L.norm_apply(qa, cfg.norm, p["ln2"], x)
+    stats = {}
     with jax.named_scope(scopes.MLP):
-        if kind == "moe":
+        if kind == "moe" and cfg.moe_strategy == "dropless":
+            y, stats = M.dropless_apply(qa, p["ffn"], h, cfg)
+        elif kind == "moe":
             y = M.moe_apply(qa, p["ffn"], h, cfg)
         else:
             y = M.mlp_apply(qa, p["ffn"], h, cfg.act_fn)
-    return qa.add(x, y), new_cache
+    return qa.add(x, y), new_cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -219,10 +225,25 @@ def _logits(qa, cfg, params, x):
     return qa.matmul_f32out(h, params["lm_head"]["kernel"])
 
 
+def _layer_stats(stats: list[dict]) -> dict:
+    """The expert layers' stats of one forward, each a stack over layers:
+    the router loss and the held share averaged, the load's maximum."""
+    if not stats:
+        return {}
+    cat = {k: jnp.concatenate([jnp.ravel(s[k]) for s in stats])
+           for k in stats[0]}
+    return {"router_loss": cat["router_loss"].mean(),
+            "held_share": cat["held_share"].mean(),
+            "max_load": cat["max_load"].max()}
+
+
 def forward(qa: QArith, params, cfg, tokens, *, positions=None,
             mrope_positions=None, remat: bool = True,
-            attn_chunk: int = 1024, logits: bool = True):
-    """Full-sequence forward. tokens: (B,S) int32 or (B,S,D) embeddings."""
+            attn_chunk: int = 1024, logits: bool = True,
+            with_stats: bool = False):
+    """Full-sequence forward. tokens: (B,S) int32 or (B,S,D) embeddings.
+    ``with_stats`` also returns the expert layers' router loss and load
+    counters (:func:`_layer_stats`; empty for a model without them)."""
     kinds, n_groups, rem = _layer_plan(cfg)
     B, Sq = tokens.shape[:2]
     if positions is None:
@@ -230,22 +251,26 @@ def forward(qa: QArith, params, cfg, tokens, *, positions=None,
     x = shard_batch(_embed_tokens(qa, cfg, params, tokens))
 
     def group_body(x, p_group):
+        stats = []
         for i, kind in enumerate(kinds):
-            x, _ = block_apply(qa, cfg, kind, p_group[f"b{i}"], x,
+            x, _, st = block_apply(qa, cfg, kind, p_group[f"b{i}"], x,
+                                   positions=positions,
+                                   mrope_positions=mrope_positions,
+                                   attn_chunk=attn_chunk)
+            x = shard_batch(x)
+            stats += [st] if st else []
+        return x, stats
+
+    body = jax.checkpoint(group_body) if remat else group_body
+    x, stats = jax.lax.scan(body, x, params["layers"])
+    for i, kind in enumerate(rem):
+        x, _, st = block_apply(qa, cfg, kind, params["rem"][f"b{i}"], x,
                                positions=positions,
                                mrope_positions=mrope_positions,
                                attn_chunk=attn_chunk)
-            x = shard_batch(x)
-        return x, None
-
-    body = jax.checkpoint(group_body) if remat else group_body
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    for i, kind in enumerate(rem):
-        x, _ = block_apply(qa, cfg, kind, params["rem"][f"b{i}"], x,
-                           positions=positions,
-                           mrope_positions=mrope_positions,
-                           attn_chunk=attn_chunk)
-    return _logits(qa, cfg, params, x) if logits else x
+        stats += [st] if st else []
+    out = _logits(qa, cfg, params, x) if logits else x
+    return (out, _layer_stats(stats)) if with_stats else out
 
 
 def decode_step(qa: QArith, params, cfg, token, cache, cache_pos, *,
@@ -269,7 +294,7 @@ def decode_step(qa: QArith, params, cfg, token, cache, cache_pos, *,
         p_group, c_group = inp
         new_c = {}
         for i, kind in enumerate(kinds):
-            x, new_c[f"b{i}"] = block_apply(
+            x, new_c[f"b{i}"], _ = block_apply(
                 qa, cfg, kind, p_group[f"b{i}"], x, positions=positions,
                 cache=c_group[f"b{i}"], cache_pos=cache_pos,
                 mrope_positions=mrope_positions, block_table=block_table)
@@ -282,7 +307,7 @@ def decode_step(qa: QArith, params, cfg, token, cache, cache_pos, *,
     if rem:
         new_cache["rem"] = {}
         for i, kind in enumerate(rem):
-            x, new_cache["rem"][f"b{i}"] = block_apply(
+            x, new_cache["rem"][f"b{i}"], _ = block_apply(
                 qa, cfg, kind, params["rem"][f"b{i}"], x, positions=positions,
                 cache=cache["rem"][f"b{i}"], cache_pos=cache_pos,
                 mrope_positions=mrope_positions, block_table=block_table)

@@ -28,6 +28,12 @@ NORM = "norm"
 HEAD = "head"                  # final norm and the vocabulary projection
 LOSS = "loss"
 
+# inside MLP, for an expert layer (moe_strategy="dropless")
+ROUTER = "router"              # router logits, softmax, top-k, its loss
+DISPATCH = "dispatch"          # sort the assignments held here by expert
+EXPERTS = "experts"            # the grouped matmuls of the held experts
+COMBINE = "combine"            # unsort, and sum each token's gated outputs
+
 # inside OPTIMIZER
 SR_BITS = "sr_bits"            # random bits for stochastic rounding
 LAYOUT = "layout"              # a flat-path leaf's copy into tiles and back
@@ -36,6 +42,7 @@ FUSED_SGD = "fused_sgd"
 
 STEP_SCOPES = (MODEL, TRANSPORT, OPTIMIZER, STEP_METRICS)
 MODEL_SCOPES = (EMBED, ATTENTION, MLP, NORM, HEAD, LOSS)
+MLP_SCOPES = (ROUTER, DISPATCH, EXPERTS, COMBINE)
 OPTIMIZER_SCOPES = (SR_BITS, LAYOUT)
 
 # host spans of run_training's loop, one per part of a step

@@ -46,6 +46,12 @@ __all__ = ["make_train_step", "make_fsdp_train_step", "make_eval_step",
 
 PyTree = Any
 
+#: an expert layer's per-step counters, reported as ``expert_<name>``:
+#: the share of the token-expert assignments held here, and the busiest
+#: held expert's assignments over the held experts' mean (averaged over
+#: microbatches; the load the largest over layers)
+LOAD_COUNTERS = ("held_share", "max_load")
+
 
 def compute_params(params: PyTree, policy: PrecisionPolicy) -> PyTree:
     """Working copy of the weights in the compute format.
@@ -135,25 +141,35 @@ def make_train_step(cfg, policy: PrecisionPolicy, optimizer, lr_schedule,
     n_wire = transport.wire_replicas
 
     def _loss(params, batch):
+        """The loss, with the expert layers' router loss at
+        ``cfg.router_aux_coef``, and their load counters (empty for a
+        model without them)."""
         with jax.named_scope(S.MODEL):
-            logits = R.forward_logits(qa, params, cfg, batch, remat=remat,
-                                      attn_chunk=attn_chunk)
+            logits, stats = R.forward_logits(qa, params, cfg, batch,
+                                             remat=remat,
+                                             attn_chunk=attn_chunk,
+                                             with_stats=True)
             with jax.named_scope(S.LOSS):
                 if loss_fn is not None:
-                    return loss_fn(logits, batch)
-                return softmax_xent(logits, batch["labels"])
+                    loss = loss_fn(logits, batch)
+                else:
+                    loss = softmax_xent(logits, batch["labels"])
+                if stats and cfg.router_aux_coef:
+                    loss = loss + cfg.router_aux_coef * stats["router_loss"]
+        return loss, {k: stats[k] for k in LOAD_COUNTERS if k in stats}
 
     def _micro_grads(wc, batch):
-        """Loss + grads of one microbatch; grads stacked (n_wire, ...)
-        on the wire axis when the transport has an explicit wire."""
+        """(Loss, load counters) + grads of one microbatch; grads stacked
+        (n_wire, ...) on the wire axis when the transport has an explicit
+        wire, and the rest averaged over it."""
         if n_wire > 1:
             chunks = _split_microbatches(batch, n_wire, "wire_replicas")
             axes = jax.tree_util.tree_map(lambda _: 0, chunks)
-            loss, grads = jax.vmap(
-                jax.value_and_grad(_loss), in_axes=(None, axes),
+            aux, grads = jax.vmap(
+                jax.value_and_grad(_loss, has_aux=True), in_axes=(None, axes),
                 spmd_axis_name=transport.wire_axis)(wc, chunks)
-            return loss.mean(), grads
-        return jax.value_and_grad(_loss)(wc, batch)
+            return jax.tree_util.tree_map(jnp.mean, aux), grads
+        return jax.value_and_grad(_loss, has_aux=True)(wc, batch)
 
     def train_step(state: TrainState, batch, seed) -> tuple[TrainState, dict]:
         key = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
@@ -173,27 +189,27 @@ def make_train_step(cfg, policy: PrecisionPolicy, optimizer, lr_schedule,
             # scatter-add ones (regression: tests/test_fsdp.py)
             mbs = _split_microbatches(batch, grad_accum, "grad_accum")
             first = jax.tree_util.tree_map(lambda x: x[0], mbs)
-            g_shape = jax.eval_shape(lambda w, m: _micro_grads(w, m)[1],
-                                     wc, first)
+            aux_shape, g_shape = jax.eval_shape(_micro_grads, wc, first)
 
             def body(carry, mb):
-                acc_loss, acc = carry
-                loss, grads = _micro_grads(wc, mb)
+                acc_aux, acc = carry
+                aux, grads = _micro_grads(wc, mb)
                 with jax.named_scope(S.TRANSPORT):
                     acc = jax.tree_util.tree_map(
                         lambda a, g: a + g.astype(jnp.float32), acc, grads)
-                return (acc_loss + loss, acc), None
+                acc_aux = jax.tree_util.tree_map(jnp.add, acc_aux, aux)
+                return (acc_aux, acc), None
 
-            init = (jnp.zeros((), jnp.float32),
-                    jax.tree_util.tree_map(
-                        lambda s: jnp.zeros(s.shape, jnp.float32), g_shape))
-            (loss, grads), _ = jax.lax.scan(body, init, mbs)
-            loss = loss / grad_accum
+            init = jax.tree_util.tree_map(
+                lambda s: jnp.zeros(s.shape, jnp.float32), (aux_shape, g_shape))
+            (aux, grads), _ = jax.lax.scan(body, init, mbs)
+            loss, counters = jax.tree_util.tree_map(
+                lambda a: a / grad_accum, aux)
             with jax.named_scope(S.TRANSPORT):
                 grads = jax.tree_util.tree_map(lambda g: g / grad_accum,
                                                grads)
         else:
-            loss, grads = _micro_grads(wc, batch)
+            (loss, counters), grads = _micro_grads(wc, batch)
         # grads arrive in the compute dtype (bf16 FMAC outputs; f32 once
         # accumulated); the transport reduces them across replicas and
         # the quantized optimizer consumes them per Algorithms 2–5.
@@ -209,6 +225,8 @@ def make_train_step(cfg, policy: PrecisionPolicy, optimizer, lr_schedule,
             new_params = transport.finalize(new_params)
         metrics = {"loss": loss.astype(jnp.float32), "lr": lr,
                    "grad_norm": _global_norm(grads)}
+        with jax.named_scope(S.STEP_METRICS):
+            metrics.update({f"expert_{k}": v for k, v in counters.items()})
         return TrainState(state.step + 1, new_params, new_opt,
                           new_residuals), metrics
 

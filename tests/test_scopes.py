@@ -111,8 +111,8 @@ def test_loop_spans_in_host_trace(tmp_path):
 
 
 def test_scope_names_are_distinct():
-    names = S.STEP_SCOPES + S.MODEL_SCOPES + S.OPTIMIZER_SCOPES + (
-        S.FUSED_ADAMW, S.FUSED_SGD)
+    names = S.STEP_SCOPES + S.MODEL_SCOPES + S.MLP_SCOPES + \
+        S.OPTIMIZER_SCOPES + (S.FUSED_ADAMW, S.FUSED_SGD)
     assert len(set(names)) == len(names)
     assert all("/" not in n and n for n in names)
 
